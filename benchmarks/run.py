@@ -618,78 +618,101 @@ def bench_serve(rows, quick: bool):
 
 # ---- dist: mesh-sharded engine vs single device -----------------------------
 
-_DIST_WORKER = r"""
-import json, time
-import numpy as np, jax, jax.numpy as jnp
-from dataclasses import replace
-from functools import partial
-from repro import engine
-from repro.data.synthetic import make_cloud
-from repro.engine import Batch, BlockSpec
-from repro.launch.mesh import make_mesh
-from repro.models import pointnet2
+def _dist_records(quick: bool) -> list[dict]:
+    """Sharded vs single-device ``engine.apply`` over every device this
+    process sees, on identical inputs."""
+    from dataclasses import replace
+    from functools import partial
 
-quick = {quick}
-n_dev = len(jax.devices())
-B, N = (n_dev, 128) if quick else (2 * n_dev, 512)
-spec = replace(pointnet2.POINTNET2_C, blocks=(
-    BlockSpec(N // 4, 8, (16, 32)), BlockSpec(N // 8, 8, (32, 48))))
-params = engine.init(jax.random.PRNGKey(0), spec)
-rng = np.random.default_rng(0)
-xyz = jnp.asarray(np.stack([make_cloud(rng, N) for _ in range(B)]))
-batch = Batch.make(xyz, key=jax.random.PRNGKey(1))
-mesh = make_mesh((n_dev, 1), ("data", "model"))
-reps = 3 if quick else 8
-out = []
-for tag, mesh_arg in (("single_device", None), ("sharded", mesh)):
-    f = jax.jit(partial(engine.apply, spec=spec, mode="lpcn",
-                        mesh=mesh_arg))
-    f(params, batch).block_until_ready()               # compile
-    t0 = time.time()
-    for _ in range(reps):
-        y = f(params, batch)
-    y.block_until_ready()
-    us = (time.time() - t0) / reps * 1e6
-    cps = B / (us / 1e6)
-    devs = n_dev if mesh_arg is not None else 1
-    out.append(dict(tag=tag, us=us, device_count=n_dev,
-                    devices_used=devs,
-                    mesh=None if mesh_arg is None else dict(mesh.shape),
-                    batch=B, n_points=N, clouds_per_s=cps,
-                    clouds_per_s_per_device=cps / devs))
-print("DIST_JSON " + json.dumps(out))
-"""
+    import jax
+    import jax.numpy as jnp
+
+    from repro import engine
+    from repro.data.synthetic import make_cloud
+    from repro.engine import Batch, BlockSpec
+    from repro.launch.mesh import make_mesh
+    from repro.models import pointnet2
+
+    n_dev = len(jax.devices())
+    B, N = (n_dev, 128) if quick else (2 * n_dev, 512)
+    spec = replace(pointnet2.POINTNET2_C, blocks=(
+        BlockSpec(N // 4, 8, (16, 32)), BlockSpec(N // 8, 8, (32, 48))))
+    params = engine.init(jax.random.PRNGKey(0), spec)
+    rng = np.random.default_rng(0)
+    xyz = jnp.asarray(np.stack([make_cloud(rng, N) for _ in range(B)]))
+    batch = Batch.make(xyz, key=jax.random.PRNGKey(1))
+    mesh = make_mesh((n_dev, 1), ("data", "model"))
+    reps = 3 if quick else 8
+    out = []
+    for tag, mesh_arg in (("single_device", None), ("sharded", mesh)):
+        f = jax.jit(partial(engine.apply, spec=spec, mode="lpcn",
+                            mesh=mesh_arg))
+        f(params, batch).block_until_ready()               # compile
+        t0 = time.time()
+        for _ in range(reps):
+            y = f(params, batch)
+        y.block_until_ready()
+        us = (time.time() - t0) / reps * 1e6
+        cps = B / (us / 1e6)
+        devs = n_dev if mesh_arg is not None else 1
+        out.append(dict(tag=tag, us=us, device_count=n_dev,
+                        devices_used=devs,
+                        platform=jax.devices()[0].platform,
+                        mesh=None if mesh_arg is None else dict(mesh.shape),
+                        batch=B, n_points=N, clouds_per_s=cps,
+                        clouds_per_s_per_device=cps / devs))
+    return out
 
 
-def bench_dist(rows, quick: bool):
-    """Mesh-sharded engine.apply (batch split over an (n, 1)
-    ("data", "model") mesh) vs the single-device fast path on identical
-    inputs.  Runs in a subprocess with a forced host platform device
-    count — the same trick as tests/test_distributed.py — so the fake
-    CPU devices can't leak into this process's jax.  Records device
-    count, mesh shape, and absolute + per-device throughput (on a CPU
-    host the fake devices share the same cores, so sharded wall-clock is
-    a schedule-overhead measurement, not a speedup claim)."""
+def start_cpu_dist_worker(quick: bool):
+    """CPU rehearsal of the dist section: a child process with forced
+    host devices (the same trick as tests/test_distributed.py).  Only
+    valid before this process imports JAX — a parent that holds a chip
+    would starve the child — so ``main`` starts it first, and only
+    under ``JAX_PLATFORMS=cpu``."""
     import subprocess
     import sys
+    if "jax" in sys.modules:
+        raise RuntimeError(
+            "the forced-host-device dist worker must start before this "
+            "process imports JAX; run `python -m benchmarks.run --only "
+            "dist` with JAX_PLATFORMS=cpu")
     n_dev = 4 if quick else 8
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count={n_dev} "
                         + env.get("XLA_FLAGS", ""))
     env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(root, "src")]
+        [root, os.path.join(root, "src")]
         + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
-    r = subprocess.run(
-        [sys.executable, "-c", _DIST_WORKER.format(quick=quick)],
-        env=env, capture_output=True, text=True, timeout=1800)
-    if r.returncode != 0:
-        raise RuntimeError(
-            f"dist bench worker failed:\nSTDOUT:\n{r.stdout}\n"
-            f"STDERR:\n{r.stderr}")
-    line = [ln for ln in r.stdout.splitlines()
-            if ln.startswith("DIST_JSON ")][-1]
-    for rec in json.loads(line[len("DIST_JSON "):]):
+    code = ("import json; from benchmarks.run import _dist_records; "
+            f"print('DIST_JSON ' + json.dumps(_dist_records({quick})))")
+    return subprocess.Popen([sys.executable, "-c", code], env=env, cwd=root,
+                            stdout=subprocess.PIPE, stderr=subprocess.PIPE,
+                            text=True)
+
+
+def bench_dist(rows, quick: bool, worker=None):
+    """Mesh-sharded engine.apply (batch split over an (n, 1)
+    ("data", "model") mesh) vs the single-device fast path on identical
+    inputs.  On a chip host it runs in this process over
+    ``jax.devices()`` (one process per chip); ``worker`` is the CPU
+    rehearsal child from :func:`start_cpu_dist_worker`.  Records device
+    count, mesh shape, and absolute + per-device throughput (on a CPU
+    host the fake devices share the same cores, so sharded wall-clock is
+    a schedule-overhead measurement, not a speedup claim)."""
+    if worker is None:
+        recs = _dist_records(quick)
+    else:
+        stdout, stderr = worker.communicate(timeout=1800)
+        if worker.returncode != 0:
+            raise RuntimeError(
+                f"dist bench worker failed:\nSTDOUT:\n{stdout}\n"
+                f"STDERR:\n{stderr}")
+        line = [ln for ln in stdout.splitlines()
+                if ln.startswith("DIST_JSON ")][-1]
+        recs = json.loads(line[len("DIST_JSON "):])
+    for rec in recs:
         tag, us = rec.pop("tag"), rec.pop("us")
         _emit(rows, f"dist_engine_{tag}_d{rec['device_count']}", us,
               f"clouds_per_s={rec['clouds_per_s']:.1f} "
@@ -718,12 +741,19 @@ def main(argv=None) -> None:
     ap.add_argument("--only", default=None)
     ap.add_argument("--out", default="results/bench.json")
     args = ap.parse_args(argv)
+    names = [n for n in SECTIONS if not args.only or n == args.only]
+    worker = None
+    if "dist" in names and os.environ.get("JAX_PLATFORMS") == "cpu":
+        worker = start_cpu_dist_worker(args.quick)   # before JAX loads
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     rows: list = []
     print("name,us_per_call,derived")
-    for name, fn in SECTIONS.items():
-        if args.only and name != args.only:
-            continue
-        fn(rows, args.quick)
+    for name in names:
+        if name == "dist":
+            bench_dist(rows, args.quick, worker=worker)
+        else:
+            SECTIONS[name](rows, args.quick)
     os.makedirs(os.path.dirname(args.out), exist_ok=True)
     json.dump([{"name": n, "us": u, "derived": d, **meta}
                for n, u, d, meta in rows],

@@ -359,7 +359,8 @@ print("sharded smoke ok: 8-device mesh engine.apply == single-device on a "
 PYEOF
 
 echo "== dist benchmark smoke (sharded vs single-device throughput) =="
-python -m benchmarks.run --quick --only dist --out results/dist_smoke.json
+JAX_PLATFORMS=cpu python -m benchmarks.run --quick --only dist \
+    --out results/dist_smoke.json
 python - <<'PYEOF'
 import json
 rows = json.load(open("results/dist_smoke.json"))

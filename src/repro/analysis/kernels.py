@@ -1,7 +1,7 @@
 """Kernel lint: static checks over ``pallas_call`` equations in a jaxpr.
 
 Nothing here executes a kernel.  We walk a (closed) jaxpr, collect
-every ``pallas_call`` equation — descending into ``pjit`` / control-flow
+every ``pallas_call`` equation — descending into ``jit`` / control-flow
 sub-jaxprs — and check each call site's grid mapping:
 
 * **K001** — the per-call VMEM block footprint (streamed operands
@@ -41,7 +41,7 @@ _SUBJAXPR_PARAMS = ("jaxpr", "call_jaxpr", "cond_jaxpr", "body_jaxpr", "branches
 
 
 def _subjaxprs(eqn):
-    """Yield every sub-jaxpr of an equation (pjit, scan, cond, ...)."""
+    """Yield every sub-jaxpr of an equation (jit, scan, cond, ...)."""
     for key in _SUBJAXPR_PARAMS:
         v = eqn.params.get(key)
         if v is None:
@@ -109,10 +109,7 @@ def _site_from_eqn(eqn, where: str) -> KernelSite:
     gm = eqn.params["grid_mapping"]
     grid = tuple(int(g) for g in gm.grid)
     comp = eqn.params.get("compiler_params") or {}
-    if hasattr(comp, "get"):
-        sem = (comp.get("mosaic") or {}).get("dimension_semantics")
-    else:  # dataclass-style compiler params on other jax versions
-        sem = getattr(getattr(comp, "mosaic", None), "dimension_semantics", None)
+    sem = getattr(comp.get("mosaic_tpu"), "dimension_semantics", None)
     name = "pallas_call"
     nsi = eqn.params.get("name_and_src_info")
     if nsi is not None:
@@ -122,8 +119,8 @@ def _site_from_eqn(eqn, where: str) -> KernelSite:
     num_inputs = getattr(gm, "num_inputs", None)
     operands = []
     for i, bm in enumerate(gm.block_mappings):
-        arr = bm.array_shape_dtype
-        block = tuple(d if isinstance(d, int) else 1 for d in bm.block_shape)
+        arr = bm.array_aval
+        block = tuple(getattr(d, "block_size", 1) for d in bm.block_shape)
         try:
             tiles = tuple(_eval_index_map(bm, p) for p in points)
         except Exception:
@@ -144,7 +141,7 @@ def _site_from_eqn(eqn, where: str) -> KernelSite:
 
 def pallas_call_sites(jaxpr, where: str = "jaxpr") -> list[KernelSite]:
     """Collect every pallas_call site in ``jaxpr`` (a ``Jaxpr`` or
-    ``ClosedJaxpr``), descending into pjit/scan/cond/while sub-jaxprs."""
+    ``ClosedJaxpr``), descending into jit/scan/cond/while sub-jaxprs."""
     jx = getattr(jaxpr, "jaxpr", jaxpr)
     sites: list[KernelSite] = []
     counters: dict[str, int] = {}

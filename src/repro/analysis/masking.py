@@ -26,7 +26,7 @@ blocks whose sampler keeps all rows (downsampled center axes are fully
 valid by construction — ``nv_levels`` goes ``None`` below a
 downsampling block — so they are deliberately excluded).
 
-The walk descends into ``pjit``/``scan``/``while``/``cond``/custom-JVP
+The walk descends into ``jit``/``scan``/``while``/``cond``/custom-JVP
 sub-jaxprs and into Pallas kernel bodies (mapping operand guardedness
 through the kernel's refs), so the in-kernel ``-BIG`` masked pools are
 analyzed too.  Nothing executes.
@@ -167,7 +167,7 @@ class _Walker:
 
             sub = self._sub_closed(eqn)
             if sub is not None and hasattr(getattr(sub, "jaxpr", sub), "eqns"):
-                if name in ("pjit", "closed_call", "core_call", "remat",
+                if name in ("jit", "closed_call", "core_call", "remat",
                             "checkpoint", "custom_jvp_call", "custom_vjp_call",
                             "custom_vjp_call_jaxpr"):
                     outs = self.run_closed(sub, ins)

@@ -105,34 +105,57 @@ class FCBackend:
     reuse_batched: Callable | None = None
 
 
+def _per_shard(fn, mesh, data, mlp):
+    """``fn(data, mlp)`` on one device, or once per data shard of
+    ``mesh``: the (B, …) ``data`` leaves and the result split along B,
+    ``mlp`` whole on every device.  Mosaic kernels cannot be partitioned
+    automatically, so a mesh-sharded forward hands each device its own
+    shard of the cloud stack."""
+    if mesh is None:
+        return fn(data, mlp)
+    from repro.dist.sharding import on_data_shards
+    return on_data_shards(fn, mesh, data, mlp)
+
+
 def dense_batched(backend: FCBackend, mlp, kind, xyz, feats, nbr_idx,
                   centers_xyz, center_feats=None, nbr_valid=None,
-                  kernel_kw=None):
+                  kernel_kw=None, mesh=None):
     """Batched dense FC through ``backend``: native entry when available,
-    else vmap of the per-cloud entry (one kernel dispatch per cloud)."""
-    if backend.dense_batched is not None:
-        return backend.dense_batched(mlp, kind, xyz, feats, nbr_idx,
-                                     centers_xyz, center_feats, nbr_valid,
-                                     kernel_kw=kernel_kw)
-    return jax.vmap(
-        lambda x, f, n, c, cf, nv: backend.dense(mlp, kind, x, f, n, c,
-                                                 cf, nv),
-        in_axes=(0, 0, 0, 0, None if center_feats is None else 0,
-                 None if nbr_valid is None else 0),
-    )(xyz, feats, nbr_idx, centers_xyz, center_feats, nbr_valid)
+    else vmap of the per-cloud entry (one kernel dispatch per cloud).
+    ``mesh`` (None = one device) runs it per data shard."""
+    def run(data, mlp):
+        xyz, feats, nbr_idx, centers_xyz, center_feats, nbr_valid = data
+        if backend.dense_batched is not None:
+            return backend.dense_batched(mlp, kind, xyz, feats, nbr_idx,
+                                         centers_xyz, center_feats,
+                                         nbr_valid, kernel_kw=kernel_kw)
+        return jax.vmap(
+            lambda x, f, n, c, cf, nv: backend.dense(mlp, kind, x, f, n, c,
+                                                     cf, nv),
+            in_axes=(0, 0, 0, 0, None if center_feats is None else 0,
+                     None if nbr_valid is None else 0),
+        )(xyz, feats, nbr_idx, centers_xyz, center_feats, nbr_valid)
+
+    return _per_shard(run, mesh, (xyz, feats, nbr_idx, centers_xyz,
+                                  center_feats, nbr_valid), mlp)
 
 
 def reuse_batched(backend: FCBackend, mlp, pool_in, slot, comp, live=None,
-                  kernel_kw=None):
+                  kernel_kw=None, mesh=None):
     """Batched reuse FC through ``backend``: native entry when available,
-    else vmap of the per-cloud entry."""
-    if backend.reuse_batched is not None:
-        return backend.reuse_batched(mlp, pool_in, slot, comp, live,
-                                     kernel_kw=kernel_kw)
-    return jax.vmap(
-        lambda p, s, c, l: backend.reuse(mlp, p, s, c, l),
-        in_axes=(0, 0, 0, None if live is None else 0),
-    )(pool_in, slot, comp, live)
+    else vmap of the per-cloud entry.  ``mesh`` as in
+    :func:`dense_batched`."""
+    def run(data, mlp):
+        pool_in, slot, comp, live = data
+        if backend.reuse_batched is not None:
+            return backend.reuse_batched(mlp, pool_in, slot, comp, live,
+                                         kernel_kw=kernel_kw)
+        return jax.vmap(
+            lambda p, s, c, l: backend.reuse(mlp, p, s, c, l),
+            in_axes=(0, 0, 0, None if live is None else 0),
+        )(pool_in, slot, comp, live)
+
+    return _per_shard(run, mesh, (pool_in, slot, comp, live), mlp)
 
 
 def data_structuring(cfg: LPCNConfig, xyz: jnp.ndarray,
@@ -384,27 +407,30 @@ def fc_lpcn(mlp: MLP, xyz, feats, nbr_idx, centers_xyz,
 def fc_traditional_batched(mlp: MLP, xyz, feats, nbr_idx, centers_xyz,
                            center_feats=None, kind: str = "sa",
                            backend: FCBackend | None = None,
-                           nbr_valid=None, kernel_kw=None):
+                           nbr_valid=None, kernel_kw=None, mesh=None):
     """Batched :func:`fc_traditional`: every array carries a leading (B,)
     axis; the MXU-heavy dense dataflow goes through the backend's batched
-    entry point (ONE kernel dispatch for the whole cloud stack)."""
+    entry point (ONE kernel dispatch for the whole cloud stack, or per
+    data shard of ``mesh``)."""
     backend = backend or FC_BACKENDS.get("reference")
     pooled = dense_batched(backend, mlp, kind, xyz, feats, nbr_idx,
-                           centers_xyz, center_feats, nbr_valid, kernel_kw)
+                           centers_xyz, center_feats, nbr_valid, kernel_kw,
+                           mesh)
     return post_pool_activation(mlp, pooled)
 
 
 def fc_lpcn_batched(mlp: MLP, xyz, feats, nbr_idx, centers_xyz,
                     islands: Islands, sched: Schedule, cfg: LPCNConfig,
                     center_feats=None, backend: FCBackend | None = None,
-                    nbr_valid=None, kernel_kw=None):
+                    nbr_valid=None, kernel_kw=None, mesh=None):
     """Batched :func:`fc_lpcn`: every array operand (including the
     ``islands`` / ``sched`` pytrees) carries a leading (B,) axis.
 
     The per-cloud jnp bookkeeping (reuse-operand prep, overflow compute,
     merge + scatter) is vmapped; the two MXU-heavy dataflows go through
-    the backend's batched entry points so the whole cloud stack reaches
-    the systolic array as ONE schedule per call site."""
+    the backend's batched entry points so the whole cloud stack (or each
+    data shard of ``mesh``) reaches the systolic array as ONE schedule
+    per call site."""
     backend = backend or get_fc_backend(cfg.fc_backend)
     pool_in, comp, slot_live, sub_vec = jax.vmap(
         lambda x, f, n, c, isl, sch, cf: _lpcn_reuse_inputs(
@@ -412,14 +438,14 @@ def fc_lpcn_batched(mlp: MLP, xyz, feats, nbr_idx, centers_xyz,
         in_axes=(0, 0, 0, 0, 0, 0, None if center_feats is None else 0),
     )(xyz, feats, nbr_idx, centers_xyz, islands, sched, center_feats)
     reuse_pooled = reuse_batched(backend, mlp, pool_in, sched.reuse_slot,
-                                 comp, slot_live, kernel_kw)
+                                 comp, slot_live, kernel_kw, mesh)
     out, fb = jax.vmap(
         lambda x, f, n, isl, sch, sv, sl, rp: _lpcn_merge(
             mlp, x, f, n, isl, sch, cfg, sv, sl, rp)
     )(xyz, feats, nbr_idx, islands, sched, sub_vec, slot_live, reuse_pooled)
     h_dense = dense_batched(backend, mlp, cfg.block_kind, xyz, feats,
                             nbr_idx, centers_xyz, center_feats, nbr_valid,
-                            kernel_kw)
+                            kernel_kw, mesh)
     out = jnp.where(fb[..., None], h_dense, out)
     return post_pool_activation(mlp, out)
 
@@ -524,10 +550,11 @@ def compute_block_features_batched(cfg: LPCNConfig, mlp: MLP, xyz, feats,
     dataflows run through the backend's batched entry points — one kernel
     dispatch per call site for the whole cloud stack.
 
-    ``mesh`` (None = single device) re-constrains the block's (B, S,
-    Fout) output along the mesh data axes, so consecutive blocks of a
-    mesh-sharded forward hand features over without a GSPMD
-    replicate/reshard at the block boundary."""
+    ``mesh`` (None = single device) runs the FC dataflows per data shard
+    and re-constrains the block's (B, S, Fout) output along the mesh
+    data axes, so consecutive blocks of a mesh-sharded forward hand
+    features over without a GSPMD replicate/reshard at the block
+    boundary."""
     backend = backend or get_fc_backend(cfg.fc_backend)
     center_feats = jnp.take_along_axis(
         feats, st.center_idx[..., None], axis=1)
@@ -536,12 +563,12 @@ def compute_block_features_batched(cfg: LPCNConfig, mlp: MLP, xyz, feats,
                                    center_feats, cfg.block_kind,
                                    backend=backend,
                                    nbr_valid=st.nbr_valid,
-                                   kernel_kw=kernel_kw)
+                                   kernel_kw=kernel_kw, mesh=mesh)
     else:
         f = fc_lpcn_batched(mlp, xyz, feats, st.nbr, st.center_xyz,
                             st.islands, st.schedule, cfg, center_feats,
                             backend=backend, nbr_valid=st.nbr_valid,
-                            kernel_kw=kernel_kw)
+                            kernel_kw=kernel_kw, mesh=mesh)
     if st.center_valid is not None:
         f = jnp.where(st.center_valid[..., None], f, 0.0)
     if mesh is not None:

@@ -12,7 +12,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -70,5 +69,5 @@ def pipeline_apply(mesh, axis: str, n_micro: int, fn, stage_params, x):
             jnp.where(idx == n_stage - 1, outs, jnp.zeros_like(outs)),
             axis)
 
-    return shard_map(stage_fn, mesh=mesh, in_specs=(P(axis), P()),
-                     out_specs=P(), check_rep=False)(stage_params, x)
+    return jax.shard_map(stage_fn, mesh=mesh, in_specs=(P(axis), P()),
+                         out_specs=P(), check_vma=False)(stage_params, x)

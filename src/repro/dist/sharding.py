@@ -285,3 +285,24 @@ def replicate(tree, mesh):
     sh = NamedSharding(mesh, P())
     return jax.tree.map(
         lambda x: jax.lax.with_sharding_constraint(x, sh), tree)
+
+
+def on_data_shards(fn, mesh, data, replicated):
+    """``fn(data, replicated)`` once per data shard (``shard_map``): every
+    array leaf of ``data`` and the result split along its leading (B,)
+    dim over the mesh's data axes, ``replicated`` whole on each device.
+
+    The engine's batched FC entries go through here: Mosaic kernels
+    cannot be partitioned automatically.  A batch that does not divide
+    over the data axes runs unsplit (GSPMD decides, as for
+    :func:`shard_leading`'s dropped specs)."""
+    dp = _dp_axes(mesh)
+    sizes = _mesh_sizes(mesh)
+    n = 1
+    for a in (dp if isinstance(dp, tuple) else (dp,)):
+        n *= sizes[a]
+    b = jax.tree.leaves(data)[0].shape[0]
+    if b % n:
+        return fn(data, replicated)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(P(dp), P()),
+                         out_specs=P(dp), check_vma=False)(data, replicated)

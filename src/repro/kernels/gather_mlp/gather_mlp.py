@@ -26,7 +26,7 @@ Two entry points:
 VMEM budget per grid step (the ``TS`` heuristic solves for this; lane-
 padded dims D'=⌈D/128⌉·128 etc., f32):
   streamed (double-buffered):  2·TS·(K·(D'+1) + Dc) · 4 B
-      raw tile (TS, K, D') + mask (TS, K) + centers (TS, Dc)
+      raw tile (TS, K, D') + mask (TS, K, 1) + centers (TS, Dc)
   intermediates:               TS·K·(H'+F') · 4 B      (x@W1, h@W2)
   resident weights:            (D'·H' + H' + H'·F' + F') · 4 B
   output tile:                 TS·F' · 4 B
@@ -75,17 +75,24 @@ def _gather_mlp_kernel(raw_ref, ctr_ref, w1_ref, b1_ref, w2_ref, b2_ref,
     out_ref[...] = jnp.max(y, axis=1).astype(out_ref.dtype)
 
 
+def _masked_max(y, mask):
+    """Masked max-pool over K: y (TS, K, F); mask (TS, K, 1) int32
+    (nonzero = live).  Invalid positions go to -BIG before the pool;
+    subsets with zero live positions zero-fill instead of returning -BIG.
+
+    The mask arrives with its trailing unit axis from the wrapper, so K
+    sits on sublanes exactly as in ``y`` and the select broadcasts along
+    lanes; Mosaic cannot expand a (TS, K) lane-major mask in-kernel."""
+    pooled = jnp.max(jnp.where(mask != 0, y, -BIG), axis=1)
+    return jnp.where(jnp.max(mask, axis=1) != 0, pooled, 0.0)
+
+
 def _gather_mlp_masked_kernel(raw_ref, ctr_ref, mask_ref, w1_ref, b1_ref,
                               w2_ref, b2_ref, out_ref, *, dc: int):
-    """Masked variant (ragged batches): invalid (subset, k) positions go
-    to -BIG before the pool; subsets with zero valid positions zero-fill
-    instead of returning -BIG."""
+    """Masked variant (ragged batches), see :func:`_masked_max`."""
     y = _mlp_pool(raw_ref[...], ctr_ref[...], w1_ref[...], b1_ref[...],
                   w2_ref[...], b2_ref[...], dc)
-    live = mask_ref[...] != 0                             # (TS, K)
-    pooled = jnp.max(jnp.where(live[..., None], y, -BIG), axis=1)
-    pooled = jnp.where(live.any(axis=1)[:, None], pooled, 0.0)
-    out_ref[...] = pooled.astype(out_ref.dtype)
+    out_ref[...] = _masked_max(y, mask_ref[...]).astype(out_ref.dtype)
 
 
 def gather_mlp_pallas(raw: jnp.ndarray, centers: jnp.ndarray,
@@ -120,10 +127,11 @@ def gather_mlp_pallas(raw: jnp.ndarray, centers: jnp.ndarray,
         in_specs = [
             pl.BlockSpec((ts, k, d), lambda i: (i, 0, 0)),
             pl.BlockSpec((ts, dc), lambda i: (i, 0)),
-            pl.BlockSpec((ts, k), lambda i: (i, 0)),
+            pl.BlockSpec((ts, k, 1), lambda i: (i, 0, 0)),
             *weight_specs,
         ]
-        args = (raw, centers, mask.astype(jnp.int32), w1, b1, w2, b2)
+        args = (raw, centers, mask.astype(jnp.int32)[..., None],
+                w1, b1, w2, b2)
     return pl.pallas_call(
         kern,
         grid=(pl.cdiv(s, ts),),
@@ -131,6 +139,7 @@ def gather_mlp_pallas(raw: jnp.ndarray, centers: jnp.ndarray,
         out_specs=pl.BlockSpec((ts, fout), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((s, fout), raw.dtype),
         interpret=interpret,
+        name="gather_mlp_per_cloud",
     )(*args)
 
 
@@ -149,10 +158,8 @@ def _gather_mlp_batched_masked_kernel(raw_ref, ctr_ref, mask_ref, w1_ref,
                                       *, dc: int):
     y = _mlp_pool(raw_ref[...][0], ctr_ref[...][0], w1_ref[...],
                   b1_ref[...], w2_ref[...], b2_ref[...], dc)
-    live = mask_ref[...][0] != 0                          # (TS, K)
-    pooled = jnp.max(jnp.where(live[..., None], y, -BIG), axis=1)
-    pooled = jnp.where(live.any(axis=1)[:, None], pooled, 0.0)
-    out_ref[...] = pooled[None].astype(out_ref.dtype)
+    out_ref[...] = _masked_max(y, mask_ref[...][0])[None].astype(
+        out_ref.dtype)
 
 
 def gather_mlp_tile_plan(s: int, k: int, d: int, dc: int, hdim: int,
@@ -296,17 +303,20 @@ def gather_mlp_batched_pallas(raw: jnp.ndarray, centers: jnp.ndarray,
     else:
         kern = functools.partial(_gather_mlp_batched_masked_kernel, dc=dc)
         in_specs = (data_specs
-                    + [pl.BlockSpec((1, ts, k), lambda bi, i: (bi, i, 0))]
+                    + [pl.BlockSpec((1, ts, k, 1),
+                                    lambda bi, i: (bi, i, 0, 0))]
                     + weight_specs)
-        args = (raw, centers, mask.astype(jnp.int32), w1, b1, w2, b2)
+        args = (raw, centers, mask.astype(jnp.int32)[..., None],
+                w1, b1, w2, b2)
     out = pl.pallas_call(
         kern,
         grid=(b, pl.cdiv(s, ts)),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, ts, fp), lambda bi, i: (bi, i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, s, fp), raw.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=tuple(plan["dimension_semantics"])),
         interpret=interpret,
+        name="gather_mlp",
     )(*args)
     return out[..., :fout]

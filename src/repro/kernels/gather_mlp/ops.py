@@ -5,7 +5,7 @@ from functools import partial
 
 import jax
 
-from repro.kernels import plans
+from repro.kernels import plans, resolve_interpret
 from .gather_mlp import (gather_mlp_batched_pallas, gather_mlp_pallas,
                          gather_mlp_tile_plan)
 from .ref import gather_mlp_ref
@@ -17,10 +17,9 @@ def gather_mlp(raw, centers, w1, b1, w2, b2, ts: int = 8,
     """Fused normalize → MLP → max-pool, one cloud.  ``mask`` (S, K)
     bool/int (None = all live) excludes ragged padding positions from the
     pool; rows with zero live positions return zeros instead of -BIG."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     return gather_mlp_pallas(raw, centers, w1, b1, w2, b2, ts=ts,
-                             interpret=interpret, mask=mask)
+                             interpret=resolve_interpret(interpret),
+                             mask=mask)
 
 
 @partial(jax.jit, static_argnames=("ts", "vmem_budget_mb", "lanes",
@@ -37,13 +36,11 @@ def gather_mlp_batched(raw, centers, w1, b1, w2, b2, ts: int | None = None,
     ``dimension_semantics`` are the ``kernel_kw`` knobs (all None = the
     autotuned plan store, else the VMEM-budget heuristic); ``mask``
     (B, S, K) as in :func:`gather_mlp`."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     return gather_mlp_batched_pallas(
         raw, centers, w1, b1, w2, b2, ts=ts,
         vmem_budget_mb=vmem_budget_mb, lanes=lanes,
-        dimension_semantics=dimension_semantics, interpret=interpret,
-        mask=mask)
+        dimension_semantics=dimension_semantics,
+        interpret=resolve_interpret(interpret), mask=mask)
 
 
 # the tile plan resolves inside the trace: a plan-store mutation (or a

@@ -8,8 +8,9 @@ The Islandization Unit's datapath (paper Fig. 13/14):
      TPU adaptation: the gather is a ONE-HOT MATMUL (M·K, C) @ (C, F) —
      a systolic-friendly reuse of the MXU instead of the FPGA's BRAM
      random port                                              (MXU)
-  3. delta compensation: + comp[subset] broadcast over k       (VPU)
-  4. masked max-pool over K                                    (VPU)
+  3. masked max-pool over K                                    (VPU)
+  4. delta compensation: + comp[subset], constant over K, so
+     added after the pool                                      (VPU)
 
 Overflow (never-cached) positions are computed by the gather_mlp kernel
 outside and merged with an elementwise max (max-pool commutes), so this
@@ -34,8 +35,8 @@ Two entry points:
 
 VMEM budget per grid step (the ``TH`` heuristic solves for this; lane-
 padded D', H', F'; f32):
-  streamed (double-buffered):  2·TH·(C·D' + M·K·2 + M·F') · 4 B
-      pool (TH, C, D') + slot/live (TH, M, K) + comp (TH, M, F')
+  streamed (double-buffered):  2·TH·(C·D' + M·K + M·F') · 4 B
+      pool (TH, C, D') + slot column (TH·M·K, 1) + comp (TH, M, F')
   one-hot + gathered:          (TH·M·K)·(TH·C) + TH·M·K·F') · 4 B
   pool MLP intermediates:      TH·C·(H' + F') · 4 B
   resident weights:            (D'·H' + H' + H'·F' + F') · 4 B
@@ -64,54 +65,60 @@ DEFAULT_SEMANTICS = ("parallel", "arbitrary")
 BIG = 3.4e38
 
 
-def _reuse_gather(pool_ref, slot_ref, comp_ref, w1_ref, b1_ref, w2_ref,
-                  b2_ref):
-    """Shared kernel body: pool MLP + one-hot reuse-gather + Δ-comp.
-    Returns (gathered (M, K, F), slot (M*K,))."""
-    _, c, d = pool_ref.shape
-    _, m, k = slot_ref.shape
-    pool = pool_ref[...].reshape(c, d)
-    h = jax.lax.dot_general(pool, w1_ref[...], (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    h = jax.nn.relu(h + b1_ref[...][None, :])
-    y = jax.lax.dot_general(h, w2_ref[...], (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    y = y + b2_ref[...][None, :]                       # (C, F)
+def _reuse_pool(pool, slot, comp, w1, b1, w2, b2, *, c: int, k: int):
+    """Shared kernel body for T islands: pool MLP + one-hot reuse-gather
+    + masked max-pool + Δ-comp.
 
-    slot = slot_ref[...].reshape(m * k)                # (M*K,)
-    onehot = (jax.lax.broadcasted_iota(jnp.int32, (m * k, c), 1)
-              == slot[:, None]).astype(jnp.float32)    # (M*K, C)
+    pool (T·C, D) hub-relative inputs; slot (T·M·K, 1) int32 cache slots
+    (−1 = not live); comp (T·M, F).  -> (T·M, F), −BIG where a subset has
+    no live position.
+
+    The T pool MLPs run as one (T·C, D) matmul; the T reuse gathers run
+    as one offset-one-hot (T·M·K, T·C) matmul — island j's slots map to
+    columns [j·C, (j+1)·C), dead slots hit no column.  ``slot`` arrives
+    as a column (the wrapper flattens it, with the liveness mask folded
+    in), so every mask broadcasts along lanes: Mosaic cannot move a
+    lane-major (M, K) tile onto sublanes in-kernel.  Δ-comp is added after
+    the pool: it is constant over K and rounding is monotone, so
+    max_k(g + comp) == max_k(g) + comp exactly."""
+    r = slot.shape[0]
+    mk = r * c // pool.shape[0]                       # M·K per island
+    h = jax.lax.dot_general(pool, w1, (((1,), (0,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    h = jax.nn.relu(h + b1[None, :])
+    y = jax.lax.dot_general(h, w2, (((1,), (0,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    y = y + b2[None, :]                                # (T*C, F)
+
+    live = slot >= 0                                   # (T*M*K, 1)
+    offset = jax.lax.broadcasted_iota(jnp.int32, (r, 1), 0) // mk * c
+    col = jnp.where(live, slot + offset, -1)
+    onehot = (jax.lax.broadcasted_iota(jnp.int32, (r, pool.shape[0]), 1)
+              == col).astype(jnp.float32)              # (T*M*K, T*C)
     gathered = jax.lax.dot_general(
         onehot, y, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)            # (M*K, F) MXU
-    gathered = gathered.reshape(m, k, -1)
-    gathered = gathered + comp_ref[...].reshape(m, 1, -1)
-    return gathered, slot
+        preferred_element_type=jnp.float32)            # (T*M*K, F) MXU
+    gathered = jnp.where(live, gathered, -BIG)
+    pooled = jnp.max(gathered.reshape(r // k, k, -1), axis=1)
+    return jnp.where(pooled > -BIG, pooled + comp, -BIG)
 
 
 def _hub_reuse_kernel(pool_ref, slot_ref, comp_ref, w1_ref, b1_ref,
-                      w2_ref, b2_ref, out_ref):
-    """pool_ref (1, C, D) hub-relative inputs; slot_ref (1, M, K) int32;
+                      w2_ref, b2_ref, out_ref, *, k: int):
+    """pool_ref (1, C, D) hub-relative inputs; slot_ref (M*K, 1) int32;
     comp_ref (1, M, F); out_ref (1, M, F)."""
-    _, m, k = slot_ref.shape
-    gathered, slot = _reuse_gather(pool_ref, slot_ref, comp_ref, w1_ref,
-                                   b1_ref, w2_ref, b2_ref)
-    live = (slot >= 0).reshape(m, k, 1)
-    gathered = jnp.where(live, gathered, -BIG)
-    out_ref[...] = jnp.max(gathered, axis=1)[None].astype(out_ref.dtype)
+    _, c, _ = pool_ref.shape
+    out = _reuse_pool(pool_ref[0], slot_ref[...], comp_ref[0], w1_ref[...],
+                      b1_ref[...], w2_ref[...], b2_ref[...], c=c, k=k)
+    out_ref[...] = out[None].astype(out_ref.dtype)
 
 
-def _hub_reuse_masked_kernel(pool_ref, slot_ref, comp_ref, live_ref,
-                             w1_ref, b1_ref, w2_ref, b2_ref, out_ref):
-    """Masked variant (ragged batches): a position is live only if its
-    slot is assigned AND the extra mask says the cache entry is resident."""
-    _, m, k = slot_ref.shape
-    gathered, slot = _reuse_gather(pool_ref, slot_ref, comp_ref, w1_ref,
-                                   b1_ref, w2_ref, b2_ref)
-    live = ((slot >= 0) & (live_ref[...].reshape(m * k) != 0)
-            ).reshape(m, k, 1)
-    gathered = jnp.where(live, gathered, -BIG)
-    out_ref[...] = jnp.max(gathered, axis=1)[None].astype(out_ref.dtype)
+def _slot_column(slot, live):
+    """Fold the optional liveness mask into the slots (dead = −1) and
+    flatten the trailing (H, M, K) axes to one (H·M·K, 1) column."""
+    if live is not None:
+        slot = jnp.where(live != 0, slot, -1)
+    return slot.astype(jnp.int32).reshape(slot.shape[:-3] + (-1, 1))
 
 
 def hub_reuse_pallas(pool_in: jnp.ndarray, slot: jnp.ndarray,
@@ -126,101 +133,47 @@ def hub_reuse_pallas(pool_in: jnp.ndarray, slot: jnp.ndarray,
     _, m, k = slot.shape
     hdim = w1.shape[1]
     fout = w2.shape[1]
-    weight_specs = [
+    in_specs = [
+        pl.BlockSpec((1, c, d), lambda i: (i, 0, 0)),
+        pl.BlockSpec((m * k, 1), lambda i: (i, 0)),
+        pl.BlockSpec((1, m, fout), lambda i: (i, 0, 0)),
         pl.BlockSpec((d, hdim), lambda i: (0, 0)),
         pl.BlockSpec((hdim,), lambda i: (0,)),
         pl.BlockSpec((hdim, fout), lambda i: (0, 0)),
         pl.BlockSpec((fout,), lambda i: (0,)),
     ]
-    data_specs = [
-        pl.BlockSpec((1, c, d), lambda i: (i, 0, 0)),
-        pl.BlockSpec((1, m, k), lambda i: (i, 0, 0)),
-        pl.BlockSpec((1, m, fout), lambda i: (i, 0, 0)),
-    ]
-    if live is None:
-        kern = _hub_reuse_kernel
-        in_specs = data_specs + weight_specs
-        args = (pool_in, slot, comp, w1, b1, w2, b2)
-    else:
-        kern = _hub_reuse_masked_kernel
-        in_specs = (data_specs
-                    + [pl.BlockSpec((1, m, k), lambda i: (i, 0, 0))]
-                    + weight_specs)
-        args = (pool_in, slot, comp, live.astype(jnp.int32), w1, b1, w2, b2)
     return pl.pallas_call(
-        kern,
+        functools.partial(_hub_reuse_kernel, k=k),
         grid=(hn,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, m, fout), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((hn, m, fout), pool_in.dtype),
         interpret=interpret,
-    )(*args)
+        name="hub_reuse_per_cloud",
+    )(pool_in, _slot_column(slot, live), comp, w1, b1, w2, b2)
 
 
 # ---- natively batched kernel: grid (B, ceil(H/TH)) --------------------------
 
-def _tiled_reuse_gather(pool_ref, slot_ref, comp_ref, w1_ref, b1_ref,
-                        w2_ref, b2_ref, *, hn: int):
+def _hub_reuse_batched_kernel(pool_ref, slot_ref, comp_ref, w1_ref, b1_ref,
+                              w2_ref, b2_ref, out_ref, *, hn: int, k: int):
     """TH islands per step.  Blocks carry a leading singleton batch axis:
-    pool (1, TH, C, D), slot (1, TH, M, K), comp (1, TH, M, F).
-
-    Returns (gathered (TH, M, K, F), slot (TH, M*K)).  The TH pool MLPs
-    run as one (TH·C, D) matmul; the TH reuse gathers run as one
-    offset-one-hot (TH·M·K, TH·C) matmul — island j's slots map to
-    columns [j·C, (j+1)·C), unassigned slots (< 0) hit no column.
+    pool (1, TH, C, D), slot (1, TH*M*K, 1), comp (1, TH, M, F).
 
     When TH does not divide H, the last step's out-of-range islands read
     padding (NaN in interpret mode) — their pool rows are zeroed before
     the shared one-hot matmul so 0·NaN can't contaminate real islands
     (their own outputs are clipped on write anyway)."""
     _, th, c, d = pool_ref.shape
-    _, _, m, k = slot_ref.shape
-    pool = pool_ref[...].reshape(th * c, d)
+    _, _, m, f = comp_ref.shape
+    pool = pool_ref[0].reshape(th * c, d)
     island_of_row = jax.lax.broadcasted_iota(jnp.int32, (th * c, 1), 0) // c
     in_range = pl.program_id(1) * th + island_of_row < hn
     pool = jnp.where(in_range, pool, 0.0)
-    h = jax.lax.dot_general(pool, w1_ref[...], (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    h = jax.nn.relu(h + b1_ref[...][None, :])
-    y = jax.lax.dot_general(h, w2_ref[...], (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    y = y + b2_ref[...][None, :]                       # (TH*C, F)
-
-    slot = slot_ref[...].reshape(th, m * k)            # (TH, M*K)
-    offset = jax.lax.broadcasted_iota(jnp.int32, (th, m * k), 0) * c
-    col = jnp.where(slot >= 0, slot + offset, -1).reshape(th * m * k)
-    onehot = (jax.lax.broadcasted_iota(jnp.int32, (th * m * k, th * c), 1)
-              == col[:, None]).astype(jnp.float32)
-    gathered = jax.lax.dot_general(
-        onehot, y, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)            # (TH*M*K, F) MXU
-    gathered = gathered.reshape(th, m, k, -1)
-    gathered = gathered + comp_ref[...].reshape(th, m, 1, -1)
-    return gathered, slot
-
-
-def _hub_reuse_batched_kernel(pool_ref, slot_ref, comp_ref, w1_ref, b1_ref,
-                              w2_ref, b2_ref, out_ref, *, hn: int):
-    _, th, m, k = slot_ref.shape
-    gathered, slot = _tiled_reuse_gather(pool_ref, slot_ref, comp_ref,
-                                         w1_ref, b1_ref, w2_ref, b2_ref,
-                                         hn=hn)
-    live = (slot >= 0).reshape(th, m, k, 1)
-    gathered = jnp.where(live, gathered, -BIG)
-    out_ref[...] = jnp.max(gathered, axis=2)[None].astype(out_ref.dtype)
-
-
-def _hub_reuse_batched_masked_kernel(pool_ref, slot_ref, comp_ref, live_ref,
-                                     w1_ref, b1_ref, w2_ref, b2_ref,
-                                     out_ref, *, hn: int):
-    _, th, m, k = slot_ref.shape
-    gathered, slot = _tiled_reuse_gather(pool_ref, slot_ref, comp_ref,
-                                         w1_ref, b1_ref, w2_ref, b2_ref,
-                                         hn=hn)
-    live = ((slot >= 0) & (live_ref[...].reshape(th, m * k) != 0)
-            ).reshape(th, m, k, 1)
-    gathered = jnp.where(live, gathered, -BIG)
-    out_ref[...] = jnp.max(gathered, axis=2)[None].astype(out_ref.dtype)
+    out = _reuse_pool(pool, slot_ref[0], comp_ref[0].reshape(th * m, f),
+                      w1_ref[...], b1_ref[...], w2_ref[...], b2_ref[...],
+                      c=c, k=k)
+    out_ref[...] = out.reshape(1, th, m, f).astype(out_ref.dtype)
 
 
 def hub_reuse_tile_plan(hn: int, c: int, m: int, k: int, d: int, hdim: int,
@@ -356,28 +309,18 @@ def hub_reuse_batched_pallas(pool_in: jnp.ndarray, slot: jnp.ndarray,
     ]
     data_specs = [
         pl.BlockSpec((1, th, c, dp), lambda bi, j: (bi, j, 0, 0)),
-        pl.BlockSpec((1, th, m, k), lambda bi, j: (bi, j, 0, 0)),
+        pl.BlockSpec((1, th * m * k, 1), lambda bi, j: (bi, j, 0)),
         pl.BlockSpec((1, th, m, fp), lambda bi, j: (bi, j, 0, 0)),
     ]
-    if live is None:
-        kern = functools.partial(_hub_reuse_batched_kernel, hn=hn)
-        in_specs = data_specs + weight_specs
-        args = (pool_in, slot, comp, w1, b1, w2, b2)
-    else:
-        kern = functools.partial(_hub_reuse_batched_masked_kernel, hn=hn)
-        in_specs = (data_specs
-                    + [pl.BlockSpec((1, th, m, k),
-                                    lambda bi, j: (bi, j, 0, 0))]
-                    + weight_specs)
-        args = (pool_in, slot, comp, live.astype(jnp.int32), w1, b1, w2, b2)
     out = pl.pallas_call(
-        kern,
+        functools.partial(_hub_reuse_batched_kernel, hn=hn, k=k),
         grid=(b, pl.cdiv(hn, th)),
-        in_specs=in_specs,
+        in_specs=data_specs + weight_specs,
         out_specs=pl.BlockSpec((1, th, m, fp), lambda bi, j: (bi, j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, hn, m, fp), pool_in.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=tuple(plan["dimension_semantics"])),
         interpret=interpret,
-    )(*args)
+        name="hub_reuse",
+    )(pool_in, _slot_column(slot, live), comp, w1, b1, w2, b2)
     return out[..., :fout]

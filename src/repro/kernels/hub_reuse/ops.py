@@ -5,7 +5,7 @@ from functools import partial
 
 import jax
 
-from repro.kernels import plans
+from repro.kernels import plans, resolve_interpret
 from .hub_reuse import (hub_reuse_batched_pallas, hub_reuse_pallas,
                         hub_reuse_tile_plan)
 from .ref import hub_reuse_ref
@@ -18,10 +18,9 @@ def hub_reuse(pool_in, slot, comp, w1, b1, w2, b2,
     ``live`` (H, M, K) bool/int (None = all resident) additionally masks
     positions whose cache entry is not actually resident (ragged
     batches)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     return hub_reuse_pallas(pool_in, slot, comp, w1, b1, w2, b2,
-                            interpret=interpret, live=live)
+                            interpret=resolve_interpret(interpret),
+                            live=live)
 
 
 @partial(jax.jit, static_argnames=("th", "vmem_budget_mb", "lanes",
@@ -40,13 +39,11 @@ def hub_reuse_batched(pool_in, slot, comp, w1, b1, w2, b2,
     are the ``kernel_kw`` knobs (all None = the autotuned plan store,
     else the VMEM-budget heuristic); ``live`` (B, H, M, K) as in
     :func:`hub_reuse`."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     return hub_reuse_batched_pallas(
         pool_in, slot, comp, w1, b1, w2, b2, th=th,
         vmem_budget_mb=vmem_budget_mb, lanes=lanes,
-        dimension_semantics=dimension_semantics, interpret=interpret,
-        live=live)
+        dimension_semantics=dimension_semantics,
+        interpret=resolve_interpret(interpret), live=live)
 
 
 # the tile plan resolves inside the trace: a plan-store mutation (or a

@@ -64,8 +64,10 @@ def gather_mlp_footprint_elems(t: int, k: int, dp: int, dc: int, hp: int,
     tile ``t``: double-buffered streamed blocks (raw tile + mask +
     centers), the (t·K, H/F) matmul intermediates, the output tile, and
     the resident weights.  Shared by :func:`gather_mlp_tile_plan`'s
-    feasibility predicate and the ``repro.analysis`` kernel linter /
-    future tile autotuner (ROADMAP item 1)."""
+    feasibility predicate, the ``repro.analysis`` kernel linter and the
+    autotuner.  Elements are counted as the blocks declare them; Mosaic
+    pads narrow minor dims (the (t, K, 1) mask, the (t, Dc) centers) to
+    128 lanes in VMEM, which this model does not count."""
     streamed = 2 * t * (k * (dp + 1) + dc)       # double-buffered in
     inter = t * k * (hp + fp)                    # x@W1, h@W2
     out = t * fp
@@ -76,8 +78,9 @@ def hub_reuse_footprint_elems(t: int, c: int, m: int, k: int, dp: int,
                               hp: int, fp: int) -> int:
     """Per-grid-step VMEM elements of the hub-reuse kernel at island
     tile ``t``; the one-hot gather's t² term is the binding constraint.
-    Shared by :func:`hub_reuse_tile_plan` and ``repro.analysis``."""
-    streamed = 2 * t * (c * dp + 2 * m * k + m * fp)
+    Shared by :func:`hub_reuse_tile_plan` and ``repro.analysis``.  The
+    (t·M·K, 1) slot column is counted unpadded, as for the gather mask."""
+    streamed = 2 * t * (c * dp + m * k + m * fp)
     onehot = (t * m * k) * (t * c)
     inter = t * c * (hp + fp) + t * m * k * fp
     out = t * m * fp

@@ -8,27 +8,19 @@ from __future__ import annotations
 import jax
 
 
-def _mk(shape, axes):
-    """jax.make_mesh across jax versions: newer releases take (and
-    default) ``axis_types``; 0.4.x does not have the argument."""
-    try:
-        return jax.make_mesh(tuple(shape), tuple(axes))
-    except TypeError:  # pragma: no cover — future jax requiring types
-        return jax.make_mesh(
-            tuple(shape), tuple(axes),
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single pod (256 chips) or 2x16x16 multi-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _mk(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
-    """Arbitrary mesh (tests / small-scale runs)."""
-    return _mk(shape, axes)
+    """Arbitrary mesh (tests / small-scale runs).  Every axis is
+    ``Auto``: the engine and ``repro.dist`` place arrays with
+    ``with_sharding_constraint``, which refuses explicit axes."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def local_mesh():

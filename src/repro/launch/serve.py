@@ -347,6 +347,8 @@ def main(argv=None):
     ap.add_argument("--serve-json", default="results/serve_trace.json",
                     help="where the trace report JSON goes ('' = skip)")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from repro.models import MODEL_ZOO
     if args.arch in MODEL_ZOO:

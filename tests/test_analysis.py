@@ -42,8 +42,8 @@ def _trace_copy(shape, block, grid, index_map, *, out_block=None,
     params = {}
     if semantics is not None:
         from jax.experimental.pallas import tpu as pltpu
-        params["compiler_params"] = dict(
-            mosaic=dict(dimension_semantics=semantics))
+        params["compiler_params"] = pltpu.CompilerParams(
+            dimension_semantics=semantics)
 
     def fn(x):
         return pl.pallas_call(

@@ -65,6 +65,37 @@ def test_hub_reuse_kernel(hn, c, m, k, d, hd, f):
                                rtol=3e-5, atol=3e-5)
 
 
+@pytest.mark.parametrize("backend,interpret,expected", [
+    ("cpu", None, True),
+    ("tpu", None, False),
+    ("gpu", True, True),        # an explicit flag is honoured anywhere
+    ("tpu", True, True),
+    ("cpu", False, False),
+])
+def test_interpret_resolution(monkeypatch, backend, interpret, expected):
+    from repro.kernels import resolve_interpret
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert resolve_interpret(interpret) is expected
+
+
+@pytest.mark.parametrize("backend", ["gpu", "METAL"])
+def test_interpret_resolution_refuses_other_platforms(monkeypatch, backend):
+    """A kernel wrapper left at interpret=None never falls back to the
+    interpreter on a platform that is neither cpu nor tpu."""
+    from repro.kernels import resolve_interpret
+    from repro.kernels.gather_mlp.ops import gather_mlp_batched
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    with pytest.raises(RuntimeError, match="no Pallas lowering"):
+        resolve_interpret(None)
+    # shapes no other test traces, so the jit cache cannot hide the check
+    raw = jnp.zeros((1, 3, 5, 7), jnp.float32)
+    ctr = jnp.zeros((1, 3, 3), jnp.float32)
+    w = (jnp.zeros((7, 11)), jnp.zeros(11), jnp.zeros((11, 13)),
+         jnp.zeros(13))
+    with pytest.raises(RuntimeError, match="no Pallas lowering"):
+        gather_mlp_batched(raw, ctr, *w)
+
+
 @pytest.mark.parametrize("b,hq,hkv,s,d,causal", [
     (1, 2, 1, 128, 32, True),
     (2, 4, 2, 256, 64, True),
