@@ -1,0 +1,9 @@
+"""Device time of center sampling (``pcn.sample``: farthest point sampling
+and the centers' gather), in ms per cloud answered in the window.
+Each instant counts to the innermost operation running; a loop's time
+outside its body counts to the scope around it."""
+from bench.metrics._stages import stage_ms
+
+
+def read(ctx):
+    return stage_ms(ctx, "sample")

@@ -18,6 +18,13 @@ f_i].  Delta compensation handles both (delta_comp.py).
 The Pallas kernels (kernels/gather_mlp, kernels/hub_reuse) implement the
 same two dataflows for the MXU; this file is their jnp oracle and the
 default CPU path.
+
+Each stage runs under a flat ``jax.named_scope`` (``pcn.octree``,
+``pcn.sample``, ``pcn.neighbors``, ``pcn.islandize``, ``pcn.schedule``,
+``pcn.reuse_inputs``, ``pcn.overflow``, ``pcn.dense_inputs``; the engine
+adds ``pcn.head``).  A scope only names the ops in their metadata, which
+a profiler trace carries as each device op's ``tf_op``; no stage scope
+encloses another.
 """
 from __future__ import annotations
 
@@ -172,11 +179,14 @@ def data_structuring(cfg: LPCNConfig, xyz: jnp.ndarray,
     components registered for use through ``engine.apply`` must accept
     ``n_valid`` — a clear TypeError points at the offender otherwise.
     """
-    tree = oct.build(xyz, n_valid=n_valid)
+    with jax.named_scope("pcn.octree"):
+        tree = oct.build(xyz, n_valid=n_valid)
     kw = {} if n_valid is None else {"n_valid": n_valid}
     try:
-        cidx = SAMPLERS.get(cfg.sampler)(
-            xyz, tree=tree, n_centers=cfg.n_centers, key=key, **kw)
+        with jax.named_scope("pcn.sample"):
+            cidx = SAMPLERS.get(cfg.sampler)(
+                xyz, tree=tree, n_centers=cfg.n_centers, key=key, **kw)
+            centers = xyz[cidx]
     except TypeError as e:
         if kw and "n_valid" in str(e):
             raise TypeError(
@@ -184,11 +194,11 @@ def data_structuring(cfg: LPCNConfig, xyz: jnp.ndarray,
                 f"the batched engine always passes; add n_valid=None to "
                 f"its signature (see core.registry docstring)") from e
         raise
-    centers = xyz[cidx]
     try:
-        nbr = NEIGHBORS.get(cfg.neighbor)(
-            xyz, centers, tree=tree, k=cfg.k, radius=cfg.radius,
-            octree_level=cfg.octree_level, **kw)
+        with jax.named_scope("pcn.neighbors"):
+            nbr = NEIGHBORS.get(cfg.neighbor)(
+                xyz, centers, tree=tree, k=cfg.k, radius=cfg.radius,
+                octree_level=cfg.octree_level, **kw)
     except TypeError as e:
         if kw and "n_valid" in str(e):
             raise TypeError(
@@ -267,11 +277,13 @@ def fc_traditional(mlp: MLP, xyz, feats, nbr_idx, centers_xyz,
     ``nbr_valid`` (S, K) bool masks ragged-batch -1 neighbor slots out of
     the pool (empty subsets become zero rows)."""
     backend = backend or FC_BACKENDS.get("reference")
-    pooled = backend.dense(mlp, kind, xyz, feats, nbr_idx, centers_xyz,
-                           center_feats, nbr_valid)
-    return post_pool_activation(mlp, pooled)
+    with jax.named_scope("pcn.dense_inputs"):
+        pooled = backend.dense(mlp, kind, xyz, feats, nbr_idx, centers_xyz,
+                               center_feats, nbr_valid)
+        return post_pool_activation(mlp, pooled)
 
 
+@jax.named_scope("pcn.reuse_inputs")
 def _lpcn_reuse_inputs(mlp: MLP, xyz, feats, nbr_idx, centers_xyz,
                        islands: Islands, sched: Schedule, cfg: LPCNConfig,
                        center_feats=None):
@@ -306,6 +318,7 @@ def _lpcn_reuse_inputs(mlp: MLP, xyz, feats, nbr_idx, centers_xyz,
     return pool_in, comp, slot_live, sub_vec
 
 
+@jax.named_scope("pcn.overflow")
 def _lpcn_merge(mlp: MLP, xyz, feats, nbr_idx, islands: Islands,
                 sched: Schedule, cfg: LPCNConfig, sub_vec, slot_live,
                 reuse_pooled):
@@ -394,14 +407,16 @@ def fc_lpcn(mlp: MLP, xyz, feats, nbr_idx, centers_xyz,
     pool_in, comp, slot_live, sub_vec = _lpcn_reuse_inputs(
         mlp, xyz, feats, nbr_idx, centers_xyz, islands, sched, cfg,
         center_feats)
-    reuse_pooled = backend.reuse(mlp, pool_in, sched.reuse_slot, comp,
-                                 slot_live)               # (H, M, Fout)
+    with jax.named_scope("pcn.dense_inputs"):
+        reuse_pooled = backend.reuse(mlp, pool_in, sched.reuse_slot, comp,
+                                     slot_live)           # (H, M, Fout)
     out, fb = _lpcn_merge(mlp, xyz, feats, nbr_idx, islands, sched, cfg,
                           sub_vec, slot_live, reuse_pooled)
-    h_dense = backend.dense(mlp, cfg.block_kind, xyz, feats, nbr_idx,
-                            centers_xyz, center_feats, nbr_valid)
-    out = jnp.where(fb[:, None], h_dense, out)
-    return post_pool_activation(mlp, out)
+    with jax.named_scope("pcn.dense_inputs"):
+        h_dense = backend.dense(mlp, cfg.block_kind, xyz, feats, nbr_idx,
+                                centers_xyz, center_feats, nbr_valid)
+        out = jnp.where(fb[:, None], h_dense, out)
+        return post_pool_activation(mlp, out)
 
 
 def fc_traditional_batched(mlp: MLP, xyz, feats, nbr_idx, centers_xyz,
@@ -413,10 +428,11 @@ def fc_traditional_batched(mlp: MLP, xyz, feats, nbr_idx, centers_xyz,
     entry point (ONE kernel dispatch for the whole cloud stack, or per
     data shard of ``mesh``)."""
     backend = backend or FC_BACKENDS.get("reference")
-    pooled = dense_batched(backend, mlp, kind, xyz, feats, nbr_idx,
-                           centers_xyz, center_feats, nbr_valid, kernel_kw,
-                           mesh)
-    return post_pool_activation(mlp, pooled)
+    with jax.named_scope("pcn.dense_inputs"):
+        pooled = dense_batched(backend, mlp, kind, xyz, feats, nbr_idx,
+                               centers_xyz, center_feats, nbr_valid,
+                               kernel_kw, mesh)
+        return post_pool_activation(mlp, pooled)
 
 
 def fc_lpcn_batched(mlp: MLP, xyz, feats, nbr_idx, centers_xyz,
@@ -437,17 +453,20 @@ def fc_lpcn_batched(mlp: MLP, xyz, feats, nbr_idx, centers_xyz,
             mlp, x, f, n, c, isl, sch, cfg, cf),
         in_axes=(0, 0, 0, 0, 0, 0, None if center_feats is None else 0),
     )(xyz, feats, nbr_idx, centers_xyz, islands, sched, center_feats)
-    reuse_pooled = reuse_batched(backend, mlp, pool_in, sched.reuse_slot,
-                                 comp, slot_live, kernel_kw, mesh)
+    with jax.named_scope("pcn.dense_inputs"):
+        reuse_pooled = reuse_batched(backend, mlp, pool_in,
+                                     sched.reuse_slot, comp, slot_live,
+                                     kernel_kw, mesh)
     out, fb = jax.vmap(
         lambda x, f, n, isl, sch, sv, sl, rp: _lpcn_merge(
             mlp, x, f, n, isl, sch, cfg, sv, sl, rp)
     )(xyz, feats, nbr_idx, islands, sched, sub_vec, slot_live, reuse_pooled)
-    h_dense = dense_batched(backend, mlp, cfg.block_kind, xyz, feats,
-                            nbr_idx, centers_xyz, center_feats, nbr_valid,
-                            kernel_kw, mesh)
-    out = jnp.where(fb[..., None], h_dense, out)
-    return post_pool_activation(mlp, out)
+    with jax.named_scope("pcn.dense_inputs"):
+        h_dense = dense_batched(backend, mlp, cfg.block_kind, xyz, feats,
+                                nbr_idx, centers_xyz, center_feats,
+                                nbr_valid, kernel_kw, mesh)
+        out = jnp.where(fb[..., None], h_dense, out)
+        return post_pool_activation(mlp, out)
 
 
 @dataclass
@@ -510,11 +529,14 @@ def structure_block(cfg: LPCNConfig, xyz: jnp.ndarray, key: jax.Array,
     else:
         n_hubs_valid = jnp.maximum(
             center_valid.sum() // cfg.island_size, 1)
-    isl = islandize(centers_xyz, n_hubs, level=cfg.octree_level,
-                    capacity=cfg.island_capacity,
-                    hub_select=cfg.hub_select, key=kisl,
-                    center_valid=center_valid, n_hubs_valid=n_hubs_valid)
-    sched = build_schedule(isl, nbr, cfg.cache_capacity)
+    with jax.named_scope("pcn.islandize"):
+        isl = islandize(centers_xyz, n_hubs, level=cfg.octree_level,
+                        capacity=cfg.island_capacity,
+                        hub_select=cfg.hub_select, key=kisl,
+                        center_valid=center_valid,
+                        n_hubs_valid=n_hubs_valid)
+    with jax.named_scope("pcn.schedule"):
+        sched = build_schedule(isl, nbr, cfg.cache_capacity)
     return BlockStructure(cidx, centers_xyz, nbr, isl, sched,
                           center_valid, nbr_valid)
 
@@ -526,7 +548,8 @@ def compute_block_features(cfg: LPCNConfig, mlp: MLP, xyz, feats,
     a pre-built :class:`BlockStructure`.  -> (S, Fout), padding centers
     zeroed."""
     backend = backend or get_fc_backend(cfg.fc_backend)
-    center_feats = feats[st.center_idx]
+    with jax.named_scope("pcn.dense_inputs"):
+        center_feats = feats[st.center_idx]
     if cfg.mode == "traditional":
         f = fc_traditional(mlp, xyz, feats, st.nbr, st.center_xyz,
                            center_feats, cfg.block_kind, backend=backend,
@@ -536,7 +559,8 @@ def compute_block_features(cfg: LPCNConfig, mlp: MLP, xyz, feats,
                     st.schedule, cfg, center_feats, backend=backend,
                     nbr_valid=st.nbr_valid)
     if st.center_valid is not None:
-        f = jnp.where(st.center_valid[:, None], f, 0.0)
+        with jax.named_scope("pcn.dense_inputs"):
+            f = jnp.where(st.center_valid[:, None], f, 0.0)
     return f
 
 
@@ -556,8 +580,9 @@ def compute_block_features_batched(cfg: LPCNConfig, mlp: MLP, xyz, feats,
     features over without a GSPMD replicate/reshard at the block
     boundary."""
     backend = backend or get_fc_backend(cfg.fc_backend)
-    center_feats = jnp.take_along_axis(
-        feats, st.center_idx[..., None], axis=1)
+    with jax.named_scope("pcn.dense_inputs"):
+        center_feats = jnp.take_along_axis(
+            feats, st.center_idx[..., None], axis=1)
     if cfg.mode == "traditional":
         f = fc_traditional_batched(mlp, xyz, feats, st.nbr, st.center_xyz,
                                    center_feats, cfg.block_kind,
@@ -570,7 +595,8 @@ def compute_block_features_batched(cfg: LPCNConfig, mlp: MLP, xyz, feats,
                             backend=backend, nbr_valid=st.nbr_valid,
                             kernel_kw=kernel_kw, mesh=mesh)
     if st.center_valid is not None:
-        f = jnp.where(st.center_valid[..., None], f, 0.0)
+        with jax.named_scope("pcn.dense_inputs"):
+            f = jnp.where(st.center_valid[..., None], f, 0.0)
     if mesh is not None:
         from repro.dist.sharding import shard_leading
         f = shard_leading(f, mesh)

@@ -10,7 +10,9 @@ and the FC backend, sampler and neighbor method are all registry-resolved.
 Forwards operate on ONE cloud; ``engine.apply`` vmaps them over a padded
 :class:`~repro.engine.params.Batch`.  The RNG key-split sequences mirror
 the legacy ``repro.models`` code exactly, so the compatibility shims are
-bit-identical to the old path.
+bit-identical to the old path.  Everything after the last block (global
+pool, global MLP, head, a segmentation decoder) runs under the
+``pcn.head`` scope, beside the stage scopes of ``core.pipeline``.
 """
 from __future__ import annotations
 
@@ -300,17 +302,19 @@ def _fwd_pointnet2(params: PCNParams, spec: PCNSpec, xyz, feats, key,
                    ctx: EngineCtx, n_valid=None):
     cx, cf, reports, saved, nv_levels = _run_blocks(params, spec, xyz,
                                                     feats, key, ctx, n_valid)
-    if spec.task == "cls":
-        g = _global_pool(params, cx, cf, n_valid=nv_levels[-1])
-        return apply_mlp(params.head, g), _total(reports)
-    # segmentation: FP decoder back up the saved pyramid
-    f = cf
-    xyz_levels = [s[0] for s in saved] + [cx]
-    for lvl in range(len(saved) - 1, -1, -1):
-        f = feature_propagation(xyz_levels[lvl], xyz_levels[lvl + 1], f,
-                                src_n_valid=nv_levels[lvl + 1])
-    # per-point logits of padding rows are zeroed (ragged contract)
-    return _mask_rows(apply_mlp(params.head, f), n_valid), _total(reports)
+    with jax.named_scope("pcn.head"):
+        if spec.task == "cls":
+            g = _global_pool(params, cx, cf, n_valid=nv_levels[-1])
+            return apply_mlp(params.head, g), _total(reports)
+        # segmentation: FP decoder back up the saved pyramid
+        f = cf
+        xyz_levels = [s[0] for s in saved] + [cx]
+        for lvl in range(len(saved) - 1, -1, -1):
+            f = feature_propagation(xyz_levels[lvl], xyz_levels[lvl + 1], f,
+                                    src_n_valid=nv_levels[lvl + 1])
+        # per-point logits of padding rows are zeroed (ragged contract)
+        return (_mask_rows(apply_mlp(params.head, f), n_valid),
+                _total(reports))
 
 
 def _fwd_pointnet2_batched(params: PCNParams, spec: PCNSpec, xyz, feats,
@@ -321,18 +325,19 @@ def _fwd_pointnet2_batched(params: PCNParams, spec: PCNSpec, xyz, feats,
         _structure_stack_b(spec, ctx, xyz, keys, n_valid), ctx)
     xyz_levels, cf = _compute_stack_b(params, spec, ctx, xyz, feats,
                                       structs)
-    if spec.task == "cls":
-        nv = nv_levels[-1]
-        g = jax.vmap(
-            lambda c, f, v: _global_pool(params, c, f, n_valid=v),
-            in_axes=(0, 0, None if nv is None else 0),
-        )(xyz_levels[-1], cf, nv)
-        return apply_mlp(params.head, g)
-    f = cf
-    for lvl in range(len(spec.blocks) - 1, -1, -1):
-        f = _fp_b(xyz_levels[lvl], xyz_levels[lvl + 1], f,
-                  nv_levels[lvl + 1])
-    return _mask_rows_b(apply_mlp(params.head, f), n_valid)
+    with jax.named_scope("pcn.head"):
+        if spec.task == "cls":
+            nv = nv_levels[-1]
+            g = jax.vmap(
+                lambda c, f, v: _global_pool(params, c, f, n_valid=v),
+                in_axes=(0, 0, None if nv is None else 0),
+            )(xyz_levels[-1], cf, nv)
+            return apply_mlp(params.head, g)
+        f = cf
+        for lvl in range(len(spec.blocks) - 1, -1, -1):
+            f = _fp_b(xyz_levels[lvl], xyz_levels[lvl + 1], f,
+                      nv_levels[lvl + 1])
+        return _mask_rows_b(apply_mlp(params.head, f), n_valid)
 
 
 ARCHS.register("pointnet2", Arch("pointnet2", _init_pointnet2,
@@ -369,15 +374,16 @@ def _fwd_dgcnn(params: PCNParams, spec: PCNSpec, xyz, feats, key,
         per_layer.append(f)
         if ctx.with_report and out.report is not None:
             reports.append(out.report)
-    cat = jnp.concatenate(per_layer, axis=-1)
-    gmax = _mask_rows(cat, n_valid, fill=-_BIG).max(axis=0)
-    if spec.task == "cls":
-        return apply_mlp(params.head, gmax), _total(reports)
-    per_point = jnp.concatenate(
-        [cat, jnp.broadcast_to(gmax[None], cat.shape[:1] + gmax.shape)],
-        axis=-1)
-    return _mask_rows(apply_mlp(params.head, per_point), n_valid), \
-        _total(reports)
+    with jax.named_scope("pcn.head"):
+        cat = jnp.concatenate(per_layer, axis=-1)
+        gmax = _mask_rows(cat, n_valid, fill=-_BIG).max(axis=0)
+        if spec.task == "cls":
+            return apply_mlp(params.head, gmax), _total(reports)
+        per_point = jnp.concatenate(
+            [cat, jnp.broadcast_to(gmax[None], cat.shape[:1] + gmax.shape)],
+            axis=-1)
+        return _mask_rows(apply_mlp(params.head, per_point), n_valid), \
+            _total(reports)
 
 
 def _structure_dgcnn(spec: PCNSpec, ctx: EngineCtx, xyz, key, n_valid):
@@ -407,14 +413,15 @@ def _fwd_dgcnn_batched(params: PCNParams, spec: PCNSpec, xyz, feats, keys,
                                            kernel_kw=kernel_kw,
                                            mesh=ctx.mesh)
         per_layer.append(f)
-    cat = jnp.concatenate(per_layer, axis=-1)
-    gmax = _mask_rows_b(cat, n_valid, fill=-_BIG).max(axis=1)
-    if spec.task == "cls":
-        return apply_mlp(params.head, gmax)
-    per_point = jnp.concatenate(
-        [cat, jnp.broadcast_to(gmax[:, None],
-                               cat.shape[:2] + gmax.shape[-1:])], axis=-1)
-    return _mask_rows_b(apply_mlp(params.head, per_point), n_valid)
+    with jax.named_scope("pcn.head"):
+        cat = jnp.concatenate(per_layer, axis=-1)
+        gmax = _mask_rows_b(cat, n_valid, fill=-_BIG).max(axis=1)
+        if spec.task == "cls":
+            return apply_mlp(params.head, gmax)
+        per_point = jnp.concatenate(
+            [cat, jnp.broadcast_to(gmax[:, None],
+                                   cat.shape[:2] + gmax.shape[-1:])], axis=-1)
+        return _mask_rows_b(apply_mlp(params.head, per_point), n_valid)
 
 
 ARCHS.register("dgcnn", Arch("dgcnn", _init_dgcnn, _fwd_dgcnn,
@@ -461,11 +468,13 @@ def _fwd_stem_stack(params, spec, xyz, feats, key, ctx, combine,
         nv_levels.append(cur_nv)
         if ctx.with_report and out.report is not None:
             reports.append(out.report)
-    for lvl in range(len(spec.blocks) - 1, -1, -1):
-        f = feature_propagation(xyz_levels[lvl], xyz_levels[lvl + 1], f,
-                                src_n_valid=nv_levels[lvl + 1])
-    # per-point logits of padding rows are zeroed (ragged contract)
-    return _mask_rows(apply_mlp(params.head, f), n_valid), _total(reports)
+    with jax.named_scope("pcn.head"):
+        for lvl in range(len(spec.blocks) - 1, -1, -1):
+            f = feature_propagation(xyz_levels[lvl], xyz_levels[lvl + 1], f,
+                                    src_n_valid=nv_levels[lvl + 1])
+        # per-point logits of padding rows are zeroed (ragged contract)
+        return (_mask_rows(apply_mlp(params.head, f), n_valid),
+                _total(reports))
 
 
 def _fwd_stem_stack_batched(params, spec, xyz, feats, keys, ctx, combine,
@@ -489,10 +498,11 @@ def _fwd_stem_stack_batched(params, spec, xyz, feats, keys, ctx, combine,
         f = combine(extra, h)
         cur_xyz = st.center_xyz
         xyz_levels.append(cur_xyz)
-    for lvl in range(len(spec.blocks) - 1, -1, -1):
-        f = _fp_b(xyz_levels[lvl], xyz_levels[lvl + 1], f,
-                  nv_levels[lvl + 1])
-    return _mask_rows_b(apply_mlp(params.head, f), n_valid)
+    with jax.named_scope("pcn.head"):
+        for lvl in range(len(spec.blocks) - 1, -1, -1):
+            f = _fp_b(xyz_levels[lvl], xyz_levels[lvl + 1], f,
+                      nv_levels[lvl + 1])
+        return _mask_rows_b(apply_mlp(params.head, f), n_valid)
 
 
 def _fwd_pointnext(params, spec, xyz, feats, key, ctx, n_valid=None):

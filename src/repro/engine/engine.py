@@ -30,8 +30,6 @@ old vmap-of-kernels dispatch for A/B measurement.
 """
 from __future__ import annotations
 
-from functools import partial
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -186,9 +184,16 @@ class PCNEngine:
         # should fail at construction, not at the first traffic batch)
         EngineCtx.make(mode=mode, fc_backend=fc_backend, isl_kw=self.isl_kw,
                        kernel_kw=self.kernel_kw, mesh=mesh)
-        self._japply = jax.jit(partial(
-            apply, spec=spec, mode=mode, fc_backend=fc_backend,
-            isl_kw=self.isl_kw, kernel_kw=self.kernel_kw, mesh=mesh))
+        isl_kw, kernel_kw = self.isl_kw, self.kernel_kw
+
+        def pcn_step(params, batch):
+            # a named function, so that the compiled program and every
+            # op's name stack in a trace read ``jit(pcn_step)``
+            return apply(params, batch, spec=spec, mode=mode,
+                         fc_backend=fc_backend, isl_kw=isl_kw,
+                         kernel_kw=kernel_kw, mesh=mesh)
+
+        self._japply = jax.jit(pcn_step)
 
     def init(self, key: jax.Array) -> PCNParams:
         return init(key, self.spec)

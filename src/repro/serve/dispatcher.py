@@ -90,6 +90,17 @@ Every non-happy path increments a counter in the metrics ``faults``
 section (rejected/shed/deadline-miss/degraded/failed/breaker-opened),
 so a chaos trace's report quantifies the damage.
 
+Spans: each stage of a request's path writes a
+``jax.profiler.TraceAnnotation`` into the profiler's host plane, on the
+clock the device events are aligned to (a span costs one check while no
+profile is taken): ``serve.admit`` (``submit``, with the ``rid``),
+``serve.fire`` (the breaker consult, fault draw and in-flight
+registration of a fired batch), and on the executor thread
+``serve.pad`` (host padding), ``serve.device`` (engine call through
+``block_until_ready``), ``serve.readback`` (copy to the host, row
+slicing, finite check) and ``serve.complete`` (resolving the outcomes).
+The spans of one batch carry its ``seq``.
+
 Thread model: admission, polling and completions may come from
 different threads — queue/result/breaker/counter state is
 lock-protected; engine execution, host padding and readback all run
@@ -114,6 +125,13 @@ from .errors import (AdmissionError, QueueFullError, RequestError,
                      UnknownRequestError, ValidationError)
 from .metrics import ServeMetrics
 from .queue import AdmissionQueue, key_data
+
+
+def _span(name: str, **meta):
+    """A profiler span named ``name`` (``serve.*``) with ``meta`` as its
+    stats."""
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation(name, **meta)
 
 
 class _PoisonedOutput(RuntimeError):
@@ -322,6 +340,12 @@ class PCNServer:
         ``poll``/``drain`` shed the request once it expires, and an
         in-flight answer completing past it is dropped.
         """
+        with _span("serve.admit") as span:
+            rid = self._admit(xyz, feats, key, deadline_s)
+            span.set_metadata(rid=rid)
+        return rid
+
+    def _admit(self, xyz, feats, key, deadline_s) -> int:
         import jax
         now = self.clock()
         ttl = self.deadline_s if deadline_s is None else deadline_s
@@ -467,24 +491,26 @@ class PCNServer:
         return Batch.from_clouds(clouds, feats=feats, key=keys,
                                  n_pad=bucket.n_points)
 
-    def _run(self, fn, batch, reqs) -> dict[int, np.ndarray]:
+    def _run(self, fn, batch, reqs, seq: int) -> dict[int, np.ndarray]:
         """Execute one callable and slice out per-request rows,
         checking every valid row is finite (a backend returning NaN is
         a fault even when nothing raised)."""
         import jax
-        out = fn(batch)
-        jax.block_until_ready(out)
-        out = np.asarray(out)
-        rows: dict[int, np.ndarray] = {}
-        for i, r in enumerate(reqs):
-            row = out[i]
-            # seg heads return (N, n_classes); valid prefix only
-            row = row[:r.n_points] if row.ndim == 2 else row
-            if not np.isfinite(row).all():
-                raise _PoisonedOutput(
-                    f"non-finite output for rid {r.rid} "
-                    f"(bucket {bucket_str(r.bucket)})")
-            rows[r.rid] = row
+        with _span("serve.device", seq=seq):
+            out = fn(batch)
+            jax.block_until_ready(out)
+        with _span("serve.readback", seq=seq):
+            out = np.asarray(out)
+            rows: dict[int, np.ndarray] = {}
+            for i, r in enumerate(reqs):
+                row = out[i]
+                # seg heads return (N, n_classes); valid prefix only
+                row = row[:r.n_points] if row.ndim == 2 else row
+                if not np.isfinite(row).all():
+                    raise _PoisonedOutput(
+                        f"non-finite output for rid {r.rid} "
+                        f"(bucket {bucket_str(r.bucket)})")
+                rows[r.rid] = row
         return rows
 
     def _register_locked(self, bucket: Bucket, reqs) -> _InFlight:
@@ -493,15 +519,16 @@ class PCNServer:
         order, atomically with the queue take and the slot check (the
         caller holds the lock), so the in-flight table never exceeds
         ``max_in_flight`` and fault steps stay deterministic."""
-        try_primary = self.breakers[bucket.key].allow_primary()
-        rec = _InFlight(seq=self._seq, bucket=bucket, reqs=reqs,
-                        batch=None, try_primary=try_primary)
-        self._seq += 1
-        if try_primary and self.faults is not None:
-            rec.step, rec.fault = self.faults.draw()
-        self._inflight[rec.seq] = rec
-        self._inflight_rids.update(r.rid for r in reqs)
-        rec.depth = len(self._inflight)
+        with _span("serve.fire", seq=self._seq):
+            try_primary = self.breakers[bucket.key].allow_primary()
+            rec = _InFlight(seq=self._seq, bucket=bucket, reqs=reqs,
+                            batch=None, try_primary=try_primary)
+            self._seq += 1
+            if try_primary and self.faults is not None:
+                rec.step, rec.fault = self.faults.draw()
+            self._inflight[rec.seq] = rec
+            self._inflight_rids.update(r.rid for r in reqs)
+            rec.depth = len(self._inflight)
         return rec
 
     def _launch(self, rec: _InFlight) -> list[int]:
@@ -523,7 +550,8 @@ class PCNServer:
         travel in the :class:`_Outcome` for ``_complete`` to judge."""
         bucket, reqs = rec.bucket, rec.reqs
         t_start = self.clock()          # service includes host padding
-        batch = rec.batch = self._build_batch(bucket, reqs)
+        with _span("serve.pad", seq=rec.seq):
+            batch = rec.batch = self._build_batch(bucket, reqs)
         rows = None
         primary_err: Exception | None = None
         fallback_err: Exception | None = None
@@ -535,15 +563,15 @@ class PCNServer:
                     rows = self._run(
                         lambda b, _fn=fn: self.faults.apply(
                             _fn, b, rec.step, rec.fault),
-                        batch, reqs)
+                        batch, reqs, rec.seq)
                 else:
-                    rows = self._run(fn, batch, reqs)
+                    rows = self._run(fn, batch, reqs, rec.seq)
             except Exception as e:      # noqa: BLE001 — judged by
                 primary_err = e         # _complete (breaker + reason)
         if rows is None and self.fallback is not None:
             try:
                 rows = self._run(self._fallback_callable_for(bucket),
-                                 batch, reqs)
+                                 batch, reqs, rec.seq)
                 degraded = True
             except Exception as e:      # noqa: BLE001 — both sides down;
                 fallback_err = e        # surfaces as RequestError
@@ -562,7 +590,7 @@ class PCNServer:
         counters, wake blocked ``take``/``drain`` — then pump newly
         due lanes into the freed slot."""
         bucket, reqs = rec.bucket, rec.reqs
-        with self._cond:
+        with _span("serve.complete", seq=rec.seq), self._cond:
             br = self.breakers[bucket.key]
             if rec.try_primary:
                 if out.primary_err is None:

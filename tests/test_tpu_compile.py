@@ -159,3 +159,31 @@ def test_traditional_forward_compiles(one_chip, monkeypatch):
                                     mode="traditional", fc_backend="pallas"),
                             params, batch)
     assert compiled.as_text().count("tpu_custom_call") >= 2
+
+
+def test_traditional_step_names_its_kernels_and_stages(one_chip,
+                                                       monkeypatch):
+    """The engine's step compiled for the chip: the program is
+    ``jit_pcn_step``, the kernels' custom calls keep their names (a trace
+    names their events after them), and the stage scopes survive in the
+    op metadata the trace carries as ``tf_op``."""
+    import re
+
+    def sd(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    eng = engine.PCNEngine(POINTNET2_C, mode="traditional",
+                           fc_backend="pallas")
+    params = jax.tree.map(sd, jax.eval_shape(
+        lambda: engine.init(jax.random.PRNGKey(0), POINTNET2_C)))
+    batch = Batch(sd(jnp.zeros((B, N, 3))), sd(jnp.zeros((B, N, 3))),
+                  sd(jnp.zeros((B, 2), jnp.uint32)),
+                  sd(jnp.zeros((B,), jnp.int32)))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with plans.bypass():
+        text = eng._japply.lower(params, batch).compile().as_text()
+    assert re.search(r"^HloModule jit_pcn_step\b", text, re.M)
+    calls = re.findall(r"^\s*%(\w+)\.\d+ = \S+ custom-call\(", text, re.M)
+    assert sorted(calls) == ["gather_mlp", "gather_mlp"]
+    scopes = set(re.findall(r'op_name="[^"]*pcn\.([a-z_]+)', text))
+    assert {"sample", "neighbors", "dense_inputs", "head"} <= scopes
