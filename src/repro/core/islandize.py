@@ -25,6 +25,13 @@ Implementation detail vs. the paper: if the occupied-voxel graph is
 disconnected and BFS saturates before every voxel is reached, remaining
 voxels are assigned to the globally nearest hub (the paper's stopping rule
 "until every central point belongs to a Hub List" assumes connectivity).
+
+The BFS runs on a dense voxel grid of the octree level with one cell of
+margin, where each of the 27 neighbours is a static shift of the flat
+grid, so no op in the loop indexes by data.  A round costs
+O(27 * 8**level) elementwise work, independent of S: cheap at level 4
+(5,832 cells), but a scene-scale configuration at level >= 7 would have
+to revisit it.
 """
 from __future__ import annotations
 
@@ -35,7 +42,6 @@ import jax
 import jax.numpy as jnp
 
 from . import morton
-from .octree import adjacent_node_keys
 from .sampling import farthest_point_sampling, index_uniform
 
 UINT32_SENTINEL = jnp.uint32(0xFFFFFFFF)
@@ -126,17 +132,11 @@ def islandize(centers: jnp.ndarray, n_hubs: int, *, level: int = 4,
 
     # voxel center coordinates (for same-round nearest-hub tie-break)
     side = 1 << level
-    vxyz = morton.decode(jnp.where(ukeys == UINT32_SENTINEL, jnp.uint32(0),
-                                   ukeys)).astype(jnp.float32)
+    valid_vox = ukeys != UINT32_SENTINEL
+    ivox = morton.decode(jnp.where(valid_vox, ukeys, jnp.uint32(0)))
+    vxyz = ivox.astype(jnp.float32)
     extent = jnp.maximum(jnp.max(chi - clo), 1e-9)
     vcenter = clo + (vxyz + 0.5) / side * extent                     # (S, 3)
-
-    # 27-neighborhood voxel ids (exact match into ukeys, else -1)
-    nkeys = adjacent_node_keys(ukeys, level, morton.MAX_DEPTH)       # (S,27)
-    npos = jnp.searchsorted(ukeys, nkeys).astype(jnp.int32)
-    npos = jnp.clip(npos, 0, S - 1)
-    nvalid = (ukeys[npos] == nkeys) & (nkeys != UINT32_SENTINEL)
-    nbr = jnp.where(nvalid, npos, -1)                                # (S,27)
 
     # ---- Step 1: hub selection -------------------------------------------
     if hub_select == "fps":
@@ -156,33 +156,66 @@ def islandize(centers: jnp.ndarray, n_hubs: int, *, level: int = 4,
 
     # ---- Step 2: multi-source BFS over occupied voxels ---------------
     INF = jnp.float32(jnp.inf)
+    INT_MAX = jnp.iinfo(jnp.int32).max
     assign0 = jnp.full((S,), -1, jnp.int32)
     # seed: hub voxels (later hub wins ties on the same voxel — rare)
     assign0 = assign0.at[hub_tgt].set(jnp.arange(n_hubs, dtype=jnp.int32),
                                       mode="drop")
-    round0 = jnp.where(assign0 >= 0, 0, jnp.iinfo(jnp.int32).max)
-    valid_vox = ukeys != UINT32_SENTINEL
+
+    # Dense grid of the octree level, one cell of margin on every side,
+    # flattened x-major.  Neighbour (dx, dy, dz) of a cell is the cell at
+    # flat offset dx*P*P + dy*P + dz.  Only the window [W, G - W) is
+    # stored: it holds every inner cell, and every neighbour of one lies in
+    # the grid.  Cells hold a hub id, UNSEEN (occupied, not yet reached)
+    # or EMPTY (no voxel, or margin: never a frontier, never reached).
+    P = side + 2
+    G = P ** 3
+    W = P * P + P + 1
+    n = G - 2 * W
+    UNSEEN, EMPTY = -1, -2
+    # voxel (x, y, z) -> grid cell (x+1, y+1, z+1), at x*P*P + y*P + z in
+    # the window; padding voxels scatter out of bounds (dropped)
+    cell = jnp.sum(ivox.astype(jnp.int32) * jnp.array([P * P, P, 1]), -1)
+    cell = jnp.where(valid_vox, cell, n)
+    g_ass = jnp.full((n,), EMPTY, jnp.int32).at[cell].set(assign0,
+                                                          mode="drop")
+    g_rnd = jnp.where(g_ass >= 0, 0, INT_MAX)
+    # per-axis channels: seed hubs' xyz, voxel centers
+    g_hub = list(hub_xyz[jnp.clip(g_ass, 0, n_hubs - 1)].T)
+    g_vc = list(jnp.zeros((n, 3), jnp.float32).at[cell].set(
+        vcenter, mode="drop").T)
+    # 27-neighbourhood in adjacent_node_keys' order (meshgrid "ij")
+    shifts = [dx * P * P + dy * P + dz
+              for dx in (-1, 0, 1) for dy in (-1, 0, 1) for dz in (-1, 0, 1)]
 
     def bfs_round(r, state):
-        assign, rnd = state
-        # neighbor assignments from previous rounds only
-        nass = jnp.where(nbr >= 0, assign[jnp.clip(nbr, 0, S - 1)], -1)
-        nrnd = jnp.where(nbr >= 0, rnd[jnp.clip(nbr, 0, S - 1)],
-                         jnp.iinfo(jnp.int32).max)
-        frontier_nbr = (nass >= 0) & (nrnd < r)                      # (S,27)
-        # distance from the candidate hub to this voxel's center
-        cand_hub_xyz = hub_xyz[jnp.clip(nass, 0, n_hubs - 1)]        # (S,27,3)
-        d = jnp.sum((cand_hub_xyz - vcenter[:, None, :]) ** 2, -1)
-        d = jnp.where(frontier_nbr, d, INF)
-        best = jnp.argmin(d, axis=-1)                                 # (S,)
-        best_hub = jnp.take_along_axis(nass, best[:, None], 1)[:, 0]
-        reach = (jnp.min(d, axis=-1) < INF) & (assign < 0) & valid_vox
-        assign = jnp.where(reach, best_hub, assign)
+        ass, rnd, hx, hy, hz = state
+        padded = [jnp.pad(a, W, constant_values=c)
+                  for a, c in ((ass, EMPTY), (hx, 0.), (hy, 0.), (hz, 0.))]
+        best_d = jnp.full((n,), INF)
+        best = [jnp.full((n,), EMPTY, jnp.int32)] + [jnp.zeros((n,))] * 3
+        for o in shifts:
+            nass, nx, ny, nz = (jax.lax.slice_in_dim(a, W + o, W + o + n)
+                                for a in padded)
+            # a cell gathered in an earlier round is a frontier; the
+            # distance from its hub to this voxel's center (refcore.sq3)
+            dx, dy, dz = nx - g_vc[0], ny - g_vc[1], nz - g_vc[2]
+            d = (dx * dx + dy * dy) + dz * dz
+            # strict "<" keeps the first of equal slots, as argmin does
+            win = (nass >= 0) & (d < best_d)
+            best_d = jnp.where(win, d, best_d)
+            best = [jnp.where(win, c, b)
+                    for c, b in zip((nass, nx, ny, nz), best)]
+        reach = (best_d < INF) & (ass == UNSEEN)
+        ass, hx, hy, hz = (jnp.where(reach, b, a)
+                           for b, a in zip(best, (ass, hx, hy, hz)))
         rnd = jnp.where(reach, r, rnd)
-        return assign, rnd
+        return ass, rnd, hx, hy, hz
 
-    assign, vrnd = jax.lax.fori_loop(1, max_rounds + 1, bfs_round,
-                                     (assign0, round0))
+    g_ass, g_rnd, *_ = jax.lax.fori_loop(1, max_rounds + 1, bfs_round,
+                                         (g_ass, g_rnd, *g_hub))
+    assign = g_ass.at[cell].get(mode="fill", fill_value=-1)
+    vrnd = g_rnd.at[cell].get(mode="fill", fill_value=INT_MAX)
 
     # fallback: disconnected voxels -> globally nearest (real) hub
     unassigned = (assign < 0) & valid_vox

@@ -109,7 +109,7 @@ def adjacent_node_keys(keys: jnp.ndarray, level: int,
 
     keys: (...,) uint32 node keys at ``level``.  Returns (..., 27) uint32.
     Out-of-bounds neighbors are replaced by the node's own key (harmless
-    duplicates for the BFS gathering use-case).
+    duplicates for a neighbourhood lookup).
     """
     side = 1 << level
     # A node key at `level` is itself a Morton code over `level` bits/axis.
