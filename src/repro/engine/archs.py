@@ -21,7 +21,7 @@ from dataclasses import dataclass, field
 import jax
 import jax.numpy as jnp
 
-from repro.core.mlp import MLP, apply_mlp, init_mlp
+from repro.core.mlp import MLP, apply_mlp, init_mlp, post_pool_activation
 from repro.core.pipeline import BIG as _BIG
 from repro.core.pipeline import (LPCNConfig, compute_block_features_batched,
                                  lpcn_block, structure_block)
@@ -348,15 +348,37 @@ ARCHS.register("pointnet2", Arch("pointnet2", _init_pointnet2,
 # ---- DGCNN (EdgeConv; every point a center) ---------------------------------
 
 def _init_dgcnn(key, spec: PCNSpec) -> PCNParams:
-    # head input is the concat of every EdgeConv output (cls) or that plus
-    # a broadcast global vector (seg) — rebuild the head accordingly
-    p = _init_pointnet2(key, spec)
+    """The EdgeConv layers, then the head: over the max-pooled per-point
+    embedding ``global_mlp`` of their concatenation (cls), or over each
+    point's concatenation beside its broadcast max (seg)."""
+    keys = jax.random.split(key, len(spec.blocks) + 2)
+    blocks, f = [], spec.in_feats
+    for sub, b in zip(keys, spec.blocks):
+        blocks.append(init_mlp(sub, [block_in_dim(b.kind, f), *b.mlp_dims],
+                               spec.activation))
+        f = b.mlp_dims[-1]
     cat_dim = sum(b.mlp_dims[-1] for b in spec.blocks)
-    head_in = cat_dim if spec.task == "cls" else 2 * cat_dim
-    key, sub = jax.random.split(key)
-    head = init_mlp(sub, [head_in, *spec.head_dims, spec.n_classes],
+    global_mlp, head_in = None, 2 * cat_dim
+    if spec.task == "cls":
+        if not spec.global_mlp:
+            raise ValueError(f"{spec.name}: DGCNN classification needs its "
+                             "per-point embedding (global_mlp)")
+        global_mlp = init_mlp(keys[-2], [cat_dim, *spec.global_mlp],
+                              spec.activation)
+        head_in = spec.global_mlp[-1]
+    head = init_mlp(keys[-1], [head_in, *spec.head_dims, spec.n_classes],
                     "per_layer")
-    return PCNParams(blocks=p.blocks, head=head, global_mlp=None)
+    return PCNParams(blocks=tuple(blocks), head=head, global_mlp=global_mlp)
+
+
+def _dgcnn_pool(params: PCNParams, cat, n_valid):
+    """The classifier's global feature of one cloud (``pcn.embed``): each
+    point's embedding of the concatenated EdgeConv outputs, its max over
+    valid points, then the embedding's activation.  cat (N, F) -> (G,)."""
+    with jax.named_scope("pcn.embed"):
+        e = apply_mlp(params.global_mlp, cat)
+        g = _mask_rows(e, n_valid, fill=-_BIG).max(axis=0)
+        return post_pool_activation(params.global_mlp, g)
 
 
 def _fwd_dgcnn(params: PCNParams, spec: PCNSpec, xyz, feats, key,
@@ -376,9 +398,12 @@ def _fwd_dgcnn(params: PCNParams, spec: PCNSpec, xyz, feats, key,
             reports.append(out.report)
     with jax.named_scope("pcn.head"):
         cat = jnp.concatenate(per_layer, axis=-1)
+    if spec.task == "cls":
+        g = _dgcnn_pool(params, cat, n_valid)
+        with jax.named_scope("pcn.head"):
+            return apply_mlp(params.head, g), _total(reports)
+    with jax.named_scope("pcn.head"):
         gmax = _mask_rows(cat, n_valid, fill=-_BIG).max(axis=0)
-        if spec.task == "cls":
-            return apply_mlp(params.head, gmax), _total(reports)
         per_point = jnp.concatenate(
             [cat, jnp.broadcast_to(gmax[None], cat.shape[:1] + gmax.shape)],
             axis=-1)
@@ -415,9 +440,13 @@ def _fwd_dgcnn_batched(params: PCNParams, spec: PCNSpec, xyz, feats, keys,
         per_layer.append(f)
     with jax.named_scope("pcn.head"):
         cat = jnp.concatenate(per_layer, axis=-1)
+    if spec.task == "cls":
+        g = jax.vmap(lambda c, v: _dgcnn_pool(params, c, v),
+                     in_axes=(0, None if n_valid is None else 0))(cat, n_valid)
+        with jax.named_scope("pcn.head"):
+            return apply_mlp(params.head, g)
+    with jax.named_scope("pcn.head"):
         gmax = _mask_rows_b(cat, n_valid, fill=-_BIG).max(axis=1)
-        if spec.task == "cls":
-            return apply_mlp(params.head, gmax)
         per_point = jnp.concatenate(
             [cat, jnp.broadcast_to(gmax[:, None],
                                    cat.shape[:2] + gmax.shape[-1:])], axis=-1)

@@ -26,7 +26,8 @@ class PCNParams:
 
     blocks:      one MLP per building block (the FC-step point MLPs).
     head:        classifier / per-point head MLP.
-    global_mlp:  final global-SA MLP (cls models; None otherwise).
+    global_mlp:  final global-SA MLP, or DGCNN's per-point embedding
+                 before its global pool (cls models; None otherwise).
     stem:        per-point input embedding (PointNeXt/PointVector; None
                  otherwise).
     extras:      per-block side branches — InvResMLP (PointNeXt) or the

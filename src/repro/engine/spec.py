@@ -32,7 +32,7 @@ class PCNSpec:
     n_classes: int
     in_feats: int = 3          # input feature dim (xyz counts as features)
     task: str = "cls"          # cls | seg
-    global_mlp: tuple = ()     # final global SA mlp (cls only)
+    global_mlp: tuple = ()     # final global SA mlp / DGCNN embedding (cls)
     activation: str = "per_layer"   # per_layer | block_end (paper §VI-E)
 
 

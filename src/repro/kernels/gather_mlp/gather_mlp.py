@@ -54,10 +54,17 @@ BIG = 3.4e38
 
 
 def _mlp_pool(raw, ctr, w1, b1, w2, b2, dc: int):
-    """Shared kernel body: normalize → 2-layer MLP.  -> (TS, K, F)."""
+    """Shared kernel body: normalize → 2-layer MLP.  -> (TS, K, F).
+
+    When the center spans every lane (``dc == D``: an EdgeConv row at a
+    lane-aligned width) the subtract is the whole input; Mosaic refuses
+    the empty slice ``raw[..., D:]`` that a concatenation would take."""
     ts, k, d = raw.shape
-    rel = raw[..., :dc] - ctr[:, None, :]
-    x = jnp.concatenate([rel, raw[..., dc:]], axis=-1)    # (TS, K, D)
+    if dc == d:
+        x = raw - ctr[:, None, :]
+    else:
+        rel = raw[..., :dc] - ctr[:, None, :]
+        x = jnp.concatenate([rel, raw[..., dc:]], axis=-1)    # (TS, K, D)
     x2 = x.reshape(ts * k, d)
     h = jax.lax.dot_general(x2, w1, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)

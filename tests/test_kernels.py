@@ -46,6 +46,33 @@ def test_gather_mlp_kernel(s, k, d, dc, h, f, dtype):
                                rtol=3e-5, atol=3e-5)
 
 
+@pytest.mark.parametrize("batched", [False, True],
+                         ids=["per_cloud", "batched"])
+def test_gather_mlp_kernel_center_spans_the_row(batched):
+    """An EdgeConv row at a lane-aligned width: the center covers all
+    D = 128 lanes (dc == D), and the kernel gives what the subtract
+    followed by a concatenation of the (empty) rest of the row gives."""
+    from repro.kernels.gather_mlp.gather_mlp import (
+        gather_mlp_batched_pallas, gather_mlp_pallas)
+    s, k, d, h, f = 16, 8, 128, 32, 64
+    raw = jnp.asarray(RNG.normal(size=(s, k, d)), jnp.float32)
+    ctr = jnp.asarray(RNG.normal(size=(s, d)), jnp.float32)
+    w1 = jnp.asarray(RNG.normal(size=(d, h)) * 0.1, jnp.float32)
+    w2 = jnp.asarray(RNG.normal(size=(h, f)) * 0.1, jnp.float32)
+    b1 = jnp.asarray(RNG.normal(size=(h,)) * 0.01, jnp.float32)
+    b2 = jnp.asarray(RNG.normal(size=(f,)) * 0.01, jnp.float32)
+    x = jnp.concatenate([raw[..., :d] - ctr[:, None, :], raw[..., d:]], -1)
+    want = (jax.nn.relu(x @ w1 + b1) @ w2 + b2).max(1)
+    if batched:
+        got = gather_mlp_batched_pallas(raw[None], ctr[None], w1, b1, w2,
+                                        b2, ts=8, interpret=True)[0]
+    else:
+        got = gather_mlp_pallas(raw, ctr, w1, b1, w2, b2, ts=8,
+                                interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=3e-5, atol=3e-5)
+
+
 @pytest.mark.parametrize("hn,c,m,k,d,hd,f", [
     (4, 64, 16, 32, 6, 64, 128),
     (2, 32, 8, 16, 9, 32, 64),

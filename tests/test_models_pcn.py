@@ -57,6 +57,9 @@ def test_dgcnn_cls_exact_reuse():
     xyz, feats = _cloud(256, seed=2)
     spec = dgcnn.with_points(dgcnn.DGCNN_C, 256)
     p = engine.init(KEY, spec)
+    # the head reads the pooled 512 -> 1,024 embedding
+    assert p.global_mlp.layers[0].w.shape == (512, 1024)
+    assert p.head.layers[0].w.shape == (1024, 512)
     l1, _ = engine.apply_single(p, xyz, feats, KEY, spec=spec, mode="lpcn")
     l0, _ = engine.apply_single(p, xyz, feats, KEY, spec=spec,
                                 mode="traditional")

@@ -1,6 +1,6 @@
 """Ahead-of-time compiles for a described TPU v5e: the FC kernels at the
-shapes of a full-width ``POINTNET2_C`` forward (B=8, N=1024), compiled
-by the chip's own compiler with no chip attached.
+shapes of full-width ``POINTNET2_C`` and ``DGCNN_C`` forwards (B=8,
+N=1024), compiled by the chip's own compiler with no chip attached.
 
 Interpret mode cannot see Mosaic's layout or VMEM refusals; these
 compiles can.  Nothing runs, so they say nothing about results or
@@ -22,9 +22,15 @@ from repro.kernels.gather_mlp.gather_mlp import (gather_mlp_batched_pallas,
                                                  gather_mlp_pallas)
 from repro.kernels.hub_reuse.hub_reuse import (hub_reuse_batched_pallas,
                                                hub_reuse_pallas)
+from repro.models.dgcnn import DGCNN_C
 from repro.models.pointnet2 import POINTNET2_C
 
 B, N = 8, 1024
+SPECS = {"pointnet2_c": POINTNET2_C, "dgcnn_c": DGCNN_C}
+#: (model, block) of every FC call site; PointNet++'s keep their ids
+BLOCKS = [("pointnet2_c", 0), ("pointnet2_c", 1)] + [
+    ("dgcnn_c", i) for i in range(len(DGCNN_C.blocks))]
+BLOCK_IDS = ["0", "1"] + [f"dgcnn_c-{i}" for i in range(len(DGCNN_C.blocks))]
 
 
 @pytest.fixture(scope="module")
@@ -53,24 +59,27 @@ def one_chip(topo):
 
 @pytest.fixture(scope="module")
 def kernel_dims():
-    """{(kernel, block): dims} as the tile planners see them in an lpcn
-    forward (traditional mode runs the same gather_mlp shapes).  Traced
-    with the store bypassed: heuristic plans, fresh kernel traces."""
-    params = jax.eval_shape(
-        lambda: engine.init(jax.random.PRNGKey(0), POINTNET2_C))
+    """{(model, kernel, block): dims} as the tile planners see them in
+    an lpcn forward (traditional mode runs the same gather_mlp shapes).
+    Traced with the store bypassed: heuristic plans, fresh kernel
+    traces."""
     batch = Batch(jax.ShapeDtypeStruct((B, N, 3), jnp.float32),
                   jax.ShapeDtypeStruct((B, N, 3), jnp.float32),
                   jax.ShapeDtypeStruct((B, 2), jnp.uint32),
                   jax.ShapeDtypeStruct((B,), jnp.int32))
-    with plans.bypass(), plans.capture() as log:
-        jax.eval_shape(partial(engine.apply, spec=POINTNET2_C, mode="lpcn",
-                               fc_backend="pallas"), params, batch)
     dims = {}
-    for e in log:
-        block = sum(k == e["kernel"] for k, _ in dims)
-        dims[e["kernel"], block] = e["dims"]
-    assert sorted(dims) == [("gather_mlp", 0), ("gather_mlp", 1),
-                            ("hub_reuse", 0), ("hub_reuse", 1)], dims
+    for model, spec in SPECS.items():
+        params = jax.eval_shape(
+            lambda: engine.init(jax.random.PRNGKey(0), spec))
+        with plans.bypass(), plans.capture() as log:
+            jax.eval_shape(partial(engine.apply, spec=spec, mode="lpcn",
+                                   fc_backend="pallas"), params, batch)
+        for e in log:
+            block = sum(m == model and k == e["kernel"]
+                        for m, k, _ in dims)
+            dims[model, e["kernel"], block] = e["dims"]
+    assert sorted(k for m, k, _ in dims) == sorted(
+        ["gather_mlp", "hub_reuse"] * len(BLOCKS)), dims
     return dims
 
 
@@ -89,9 +98,11 @@ def _weights(sd, d):
                          ids=["batched", "per_cloud"])
 @pytest.mark.parametrize("masked", [True, False],
                          ids=["masked", "unmasked"])
-@pytest.mark.parametrize("block", [0, 1])
+@pytest.mark.parametrize("block", BLOCKS, ids=BLOCK_IDS)
 def test_gather_mlp_compiles(one_chip, kernel_dims, block, masked, batched):
-    d = kernel_dims["gather_mlp", block]
+    """DGCNN's edge rows fill every lane from layer 2 on (D = 2F = 128,
+    256): the kernel's center subtract then spans the whole row."""
+    d = kernel_dims[block[0], "gather_mlp", block[1]]
 
     def sd(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -115,9 +126,9 @@ def test_gather_mlp_compiles(one_chip, kernel_dims, block, masked, batched):
                          ids=["batched", "per_cloud"])
 @pytest.mark.parametrize("masked", [True, False],
                          ids=["masked", "unmasked"])
-@pytest.mark.parametrize("block", [0, 1])
+@pytest.mark.parametrize("block", BLOCKS, ids=BLOCK_IDS)
 def test_hub_reuse_compiles(one_chip, kernel_dims, block, masked, batched):
-    d = kernel_dims["hub_reuse", block]
+    d = kernel_dims[block[0], "hub_reuse", block[1]]
 
     def sd(shape, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -138,16 +149,12 @@ def test_hub_reuse_compiles(one_chip, kernel_dims, block, masked, batched):
     _compile(fn, *data, *_weights(sd, d))
 
 
-def test_traditional_forward_compiles(one_chip, monkeypatch):
-    """The whole traditional ``POINTNET2_C`` forward at B=8, N=1024
-    compiles for the chip with both blocks' gather_mlp kernels in it.
-    (The lpcn forward takes about a minute to compile on a CPU host and
-    is left to ``chip_smoke.py``.)"""
+def _traditional_forward(one_chip, monkeypatch, spec):
     def sd(x):
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
 
     params = jax.tree.map(sd, jax.eval_shape(
-        lambda: engine.init(jax.random.PRNGKey(0), POINTNET2_C)))
+        lambda: engine.init(jax.random.PRNGKey(0), spec)))
     batch = Batch(sd(jnp.zeros((B, N, 3))), sd(jnp.zeros((B, N, 3))),
                   sd(jnp.zeros((B, 2), jnp.uint32)),
                   sd(jnp.zeros((B,), jnp.int32)))
@@ -155,10 +162,24 @@ def test_traditional_forward_compiles(one_chip, monkeypatch):
     # bypass drops (on entry and exit) any interpret-mode kernel trace
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     with plans.bypass():
-        compiled = _compile(partial(engine.apply, spec=POINTNET2_C,
+        compiled = _compile(partial(engine.apply, spec=spec,
                                     mode="traditional", fc_backend="pallas"),
                             params, batch)
-    assert compiled.as_text().count("tpu_custom_call") >= 2
+    return compiled.as_text().count("tpu_custom_call")
+
+
+def test_traditional_forward_compiles(one_chip, monkeypatch):
+    """The whole traditional ``POINTNET2_C`` forward at B=8, N=1024
+    compiles for the chip with both blocks' gather_mlp kernels in it.
+    (The lpcn forward takes about a minute to compile on a CPU host and
+    is left to ``chip_smoke.py``.)"""
+    assert _traditional_forward(one_chip, monkeypatch, POINTNET2_C) >= 2
+
+
+def test_dgcnn_traditional_forward_compiles(one_chip, monkeypatch):
+    """The traditional ``DGCNN_C`` forward (four EdgeConv layers, the
+    embedding and the head) compiles with its four gather_mlp kernels."""
+    assert _traditional_forward(one_chip, monkeypatch, DGCNN_C) >= 4
 
 
 def test_traditional_step_names_its_kernels_and_stages(one_chip,
