@@ -12,8 +12,23 @@ in closed form* (DESIGN.md §2): for the island's flattened point sequence
 cache slots to the first ``cache_capacity`` distinct points in order, and
 derive a ``reuse_slot`` map for every (subset, k) position.  Point identity
 is the index into the input cloud — semantically identical to the paper's
-Morton-code Hub-Octree probe (see tests/test_overlap_octree_equiv.py which
-proves the equivalence against ``octree.contains``).
+Morton-code Hub-Octree probe (tests/test_hub_schedule.py checks the
+equivalence against ``octree.contains``).
+
+Every step is an equality compare of ids fed straight into a reduction over
+the island's positions, with no sort, scatter or per-element gather:
+
+1. a position is a first occurrence when it is live (id >= 0) and no
+   earlier position holds its id (compare, any-reduce);
+2. first occurrences take slots by a cumulative sum, and those below
+   ``cache_capacity`` are cached;
+3. a position's ``reuse_slot`` is the slot of the cached position holding
+   its id, else -1 (compare, max-reduce);
+4. slot c of the pool holds the id of the cached position given slot c
+   (one-hot compare against the slots, max-reduce).
+
+Each compare is fused into its reduction by XLA, so no (M·K)² array is
+materialised; the only gather left reads the subsets' rows of ``nbr_idx``.
 
 Results in the pool are stored *relative to the hub center* so every
 non-hub subset needs exactly one compensation delta (c_hub - c_subset),
@@ -88,37 +103,27 @@ def build_schedule(islands: Islands, nbr_idx: jnp.ndarray,
         """ids_hmk: (M, K) -> schedule slices for one island."""
         flat = ids_hmk.reshape(-1)                                # (M*K,)
         n = flat.shape[0]
-        seq = jnp.arange(n)
-        # sort by (id, seq): group occurrences of the same point together
-        order = jnp.lexsort((seq, flat))
-        sflat = flat[order]
-        first_in_group = jnp.concatenate(
-            [jnp.array([True]), sflat[1:] != sflat[:-1]])
-        # leader position (in original sequence) of each group.  Propagate
-        # the group-start *sorted index* (monotonic, so a max-scan is a
-        # correct segmented broadcast), then map through `order`.
-        group_start = jax.lax.associative_scan(
-            jnp.maximum, jnp.where(first_in_group, seq, 0))
-        leader_seq = order[group_start]
-        # scatter back to sequence order
-        is_first = jnp.zeros((n,), bool).at[order].set(first_in_group)
-        leader_of = jnp.zeros((n,), jnp.int32).at[order].set(
-            leader_seq.astype(jnp.int32))
+        seq = jnp.arange(n, dtype=jnp.int32)
         # invalid positions (padding) never occupy or hit slots
         live = flat >= 0
-        is_first = is_first & live
-        # slot of a *leader* position: rank among leaders in sequence order
+        # (q, p) pairs: row q is the earlier candidate, column p the probe;
+        # each compare feeds its reduce over q and is never materialised
+        same = flat[:, None] == flat[None, :]
+        # first occurrence: no earlier position holds the same id
+        seen = jnp.any(same & (seq[:, None] < seq[None, :]), axis=0)
+        is_first = live & ~seen
+        # slot of a first occurrence: rank among them in sequence order
         slot_of_pos = jnp.where(is_first, jnp.cumsum(is_first) - 1, -1)
-        cached_leader = is_first & (slot_of_pos < C)
-        # per position: slot of its leader (or -1 if leader not cached)
-        leader_slot = slot_of_pos[leader_of]
-        leader_cached = cached_leader[leader_of]
-        reuse = jnp.where(live & leader_cached, leader_slot, -1)
-        # pool contents: ids of cached leaders, scattered by slot
-        pool = jnp.full((C,), -1, jnp.int32)
-        pool = pool.at[jnp.where(cached_leader, slot_of_pos, C)].set(
-            jnp.where(cached_leader, flat, -1), mode="drop")
-        return (pool, reuse.reshape(M, K).astype(jnp.int32),
+        cached = is_first & (slot_of_pos < C)
+        # per position: slot of the cached first occurrence of its id
+        # (a padding position's -1 matches no cached, hence live, id)
+        reuse = jnp.max(jnp.where(same & cached[:, None],
+                                  slot_of_pos[:, None], -1), axis=0)
+        # pool contents: id of the cached first occurrence given each slot
+        in_slot = cached[None, :] & (
+            slot_of_pos[None, :] == jnp.arange(C, dtype=jnp.int32)[:, None])
+        pool = jnp.max(jnp.where(in_slot, flat[None, :], -1), axis=1)
+        return (pool.astype(jnp.int32), reuse.reshape(M, K).astype(jnp.int32),
                 is_first.reshape(M, K))
 
     pool, reuse, first = jax.vmap(per_island)(ids)

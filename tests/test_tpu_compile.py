@@ -1,6 +1,7 @@
 """Ahead-of-time compiles for a described TPU v5e: the FC kernels at the
 shapes of full-width ``POINTNET2_C`` and ``DGCNN_C`` forwards (B=8,
-N=1024), compiled by the chip's own compiler with no chip attached.
+N=1024), and the hub schedule at theirs, compiled by the chip's own
+compiler with no chip attached.
 
 Interpret mode cannot see Mosaic's layout or VMEM refusals; these
 compiles can.  Nothing runs, so they say nothing about results or
@@ -16,6 +17,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro import engine
+from repro.core.hub_schedule import build_schedule
+from repro.core.islandize import Islands
 from repro.engine import Batch
 from repro.kernels import plans
 from repro.kernels.gather_mlp.gather_mlp import (gather_mlp_batched_pallas,
@@ -208,3 +211,27 @@ def test_traditional_step_names_its_kernels_and_stages(one_chip,
     assert sorted(calls) == ["gather_mlp", "gather_mlp"]
     scopes = set(re.findall(r'op_name="[^"]*pcn\.([a-z_]+)', text))
     assert {"sample", "neighbors", "dense_inputs", "head"} <= scopes
+
+
+#: (S, K, H, M, C) of every production hub schedule: pointnet2_c's two
+#: blocks and dgcnn_c's EdgeConv layers
+SCHEDULES = {"pn2c-1": (512, 32, 16, 64, 64), "pn2c-2": (128, 64, 4, 64, 128),
+             "dgcnn": (1024, 20, 32, 64, 40)}
+
+
+@pytest.mark.parametrize("shape", list(SCHEDULES), ids=list(SCHEDULES))
+def test_hub_schedule_compiles_without_pairwise_temporaries(one_chip, shape):
+    """The schedule's id compares fuse into their reductions: a batch of
+    16 clouds needs under 8 MiB of temporaries, where one materialised
+    (M·K)² compare per island would take 0.8 to 1 GiB."""
+    S, K, H, M, C = SCHEDULES[shape]
+    b = 16
+
+    def sd(dims, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    islands = Islands(sd((b, H, M)), sd((b, H)), sd((b, S), jnp.bool_),
+                      sd((b, S)))
+    compiled = jax.jit(jax.vmap(partial(build_schedule, cache_capacity=C))
+                       ).lower(islands, sd((b, S, K))).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2**20
